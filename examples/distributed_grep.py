@@ -19,17 +19,18 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+from repro.compile_cache import configure_compile_cache  # noqa: E402
 from repro.core import baselines, distributed  # noqa: E402
 from repro.data import corpus  # noqa: E402
-from repro.dist.compat import make_mesh  # noqa: E402
 
 
 def main():
+    configure_compile_cache()
     n = 8 * 1_000_000
     text = corpus.make_corpus("english", n, seed=0)
     patterns = [b"the ", b"people", b"government "]
 
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     print(f"mesh: {mesh.devices.shape} over axis 'data'")
     find = distributed.make_distributed_find(mesh, "data")
     count = distributed.make_distributed_count(mesh, "data")

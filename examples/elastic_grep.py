@@ -57,6 +57,7 @@ def main():
 
     import jax
 
+    from repro.compile_cache import configure_compile_cache
     from repro.core import engine
     from repro.core.remote_source import FakeObjectStore
     from repro.core.shard_stream import PartialScanResult, ShardedStreamScanner
@@ -65,6 +66,7 @@ def main():
     from repro.dist.fault_tolerance import BackoffPolicy
     from repro.obs import Recorder
 
+    configure_compile_cache()
     queries = make_queries()
     plans = engine.compile_patterns(queries)
 

@@ -16,11 +16,13 @@ import argparse
 import numpy as np
 
 from repro.approx import kmismatch_naive
+from repro.compile_cache import configure_compile_cache
 from repro.core import engine
 from repro.data import corpus
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--k", type=int, default=1)
     ap.add_argument("--size", type=int, default=200_000)

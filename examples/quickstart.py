@@ -10,12 +10,14 @@ import numpy as np
 
 import jax
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import baselines, epsm
 from repro.core.multipattern import PatternSet, find_multi
 from repro.data import corpus
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=1_000_000)
     args = ap.parse_args()

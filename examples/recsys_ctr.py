@@ -11,6 +11,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import reduced_config
 from repro.data.recsys_data import make_batch
 from repro.models import recsys as rs
@@ -19,6 +20,7 @@ from repro.train.optimizer import AdamWConfig
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=256)

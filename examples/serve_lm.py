@@ -12,6 +12,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import reduced_config
 from repro.data.pipeline import VOCAB
 from repro.models import transformer as tf
@@ -21,6 +22,7 @@ from repro.train.optimizer import adamw_init
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--max-new", type=int, default=48)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")

@@ -110,10 +110,12 @@ def main():
 
     import jax
 
+    from repro.compile_cache import configure_compile_cache
     from repro.core import engine
     from repro.core.shard_stream import ShardedStreamScanner
     from repro.core.stream import StreamScanner
 
+    configure_compile_cache()
     queries = make_queries()
     plans = engine.compile_patterns(queries)
     sc = ShardedStreamScanner(plans, args.shards or None, args.chunk)

@@ -19,6 +19,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import engine
 from repro.core.stream import StreamScanner
 
@@ -70,6 +71,7 @@ def corpus(total: int, queries, seam_starts):
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", type=int, default=1_000_000_000)
     ap.add_argument("--chunk", type=int, default=1 << 22)

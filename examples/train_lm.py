@@ -13,6 +13,7 @@ import dataclasses
 
 import jax
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import reduced_config, get_arch
 from repro.data import corpus
 from repro.data.pipeline import LMDataPipeline, VOCAB
@@ -23,6 +24,7 @@ from repro.train.optimizer import AdamWConfig
 
 
 def main():
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)

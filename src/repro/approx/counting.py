@@ -101,7 +101,7 @@ def _approx_candidates(
     (independent of P and k), compacted to APPROX_CAND_BLOCK granularity.
     The fingerprint itself is a shared-prefix read from the FingerprintBank
     — exact and approx plans of any length split one pass over `packed`."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     if bank is None:
         bank = FingerprintBank(index.packed)
     h = bank.window_fp(plan.m, plan.kbits)
@@ -169,7 +169,7 @@ def count_group_approx(
 ) -> jnp.ndarray:
     """int32 (B, P) k-mismatch occurrence counts: relaxed-LUT sparse path
     when the plan carries a usable gate, dense counting otherwise."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     C = APPROX_CAND_BLOCK
     # Same shape as the exact engine's count heuristic, re-measured for the
     # k-mismatch costs: dense packed counting is ~1 lane-op per window word

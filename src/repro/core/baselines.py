@@ -5,6 +5,9 @@ algorithms of the Faro-Lecroq survey.  We implement the representative set
 that transfers to a JAX/TPU word-RAM model:
 
   * ``naive_np``      — scalar numpy oracle (tests only).
+  * ``find_all``      — ``bytes.find`` loop: the host reference that the
+                        service and stream checks compare against at
+                        corpus sizes where ``naive_np`` is too slow.
   * ``packed_naive``  — vectorized shifted-AND over the full pattern (what
                         "naive" becomes once you have wide vector compares).
   * ``shift_or``      — SO [Baeza-Yates & Gonnet 1992]: bit-parallel NFA,
@@ -65,6 +68,18 @@ def naive_np(text, pattern) -> np.ndarray:
         if np.array_equal(t[i : i + m], p):
             mask[i] = True
     return mask
+
+
+def find_all(buf: bytes, pattern: bytes) -> np.ndarray:
+    """int64 start offsets of every occurrence of ``pattern`` in ``buf``,
+    overlapping ones included (``bytes.count`` skips those).  Plain CPython
+    string search: it shares no code with the engine."""
+    out = []
+    i = buf.find(pattern)
+    while i >= 0:
+        out.append(i)
+        i = buf.find(pattern, i + 1)
+    return np.asarray(out, np.int64)
 
 
 # ---------------------------------------------------------------------------

@@ -21,17 +21,16 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import epsm
 from repro.core.packing import as_u8
-from repro.dist.compat import axis_size as _axis_size_of, shard_map
 
 AxisNames = Union[str, tuple]
 
 
 def _axis_size(axis_names: AxisNames) -> jnp.ndarray:
     if isinstance(axis_names, str):
-        return _axis_size_of(axis_names)
+        return lax.axis_size(axis_names)
     size = 1
     for a in axis_names:
-        size = size * _axis_size_of(a)
+        size = size * lax.axis_size(a)
     return size
 
 
@@ -40,14 +39,14 @@ def _flat_index(axis_names: AxisNames) -> jnp.ndarray:
         return lax.axis_index(axis_names)
     idx = jnp.int32(0)
     for a in axis_names:
-        idx = idx * _axis_size_of(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
 def _next_rank_halo(shard: jnp.ndarray, halo: int, axis_names: AxisNames) -> jnp.ndarray:
     """Exact next-flat-rank halo exchange (handles multi-axis sharding)."""
     if isinstance(axis_names, str):
-        k = _axis_size_of(axis_names)
+        k = lax.axis_size(axis_names)
         head = lax.ppermute(
             shard[:halo], axis_names, perm=[(i, (i - 1) % k) for i in range(k)]
         )
@@ -60,7 +59,7 @@ def _next_rank_halo(shard: jnp.ndarray, halo: int, axis_names: AxisNames) -> jnp
     # ppermutes is fragile; instead use ppermute over each axis with the
     # boundary-carry trick: receive from (flat+1), i.e. send to (flat-1).
     fast = names[-1]
-    kf = _axis_size_of(fast)
+    kf = lax.axis_size(fast)
     # everyone sends head to previous rank on fast axis
     recv_fast = lax.ppermute(head, fast, perm=[(i, (i - 1) % kf) for i in range(kf)])
     if len(names) == 1:
@@ -72,7 +71,7 @@ def _next_rank_halo(shard: jnp.ndarray, halo: int, axis_names: AxisNames) -> jnp
     slow = names[:-1]
     carried = recv_fast
     for a in reversed(slow):
-        k = _axis_size_of(a)
+        k = lax.axis_size(a)
         carried = lax.ppermute(carried, a, perm=[(i, (i - 1) % k) for i in range(k)])
     at_boundary = lax.axis_index(fast) == kf - 1
     head_next = jnp.where(at_boundary, carried, recv_fast)
@@ -95,7 +94,7 @@ def make_distributed_find(mesh, axis_names: AxisNames = "data", *, algo: str = "
         tail_ok = jnp.arange(ln) <= (ln - m)
         return jnp.where(is_last, mask & tail_ok, mask)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec, P()), out_specs=spec, check_vma=False
     )
     return fn
@@ -116,7 +115,7 @@ def make_distributed_count(mesh, axis_names: AxisNames = "data", *, algo: str = 
         local_count = mask.sum(dtype=jnp.int32)
         return lax.psum(local_count, axis_names)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(find_fn_local_spec, P()),

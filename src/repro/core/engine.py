@@ -63,7 +63,7 @@ from repro.core.packing import (
     fingerprint_weights,
     fp_accum_word,
     fp_finalize,
-    hash_blocks,
+    hash_text_blocks,
     pack_u32,
     shift_left,
 )
@@ -100,6 +100,14 @@ DENSE_ROUTE_RHO = 0.5
 # is noise.  32 keeps the verified-position inflation (block granularity vs
 # true candidates) small; 128 measured ~1.6x slower end to end.
 CAND_BLOCK = 32
+# count_many / match_many over a text longer than this scan it as a loop
+# over windows of this many bytes (plus the max_m - 1 overlap), so
+# their device temporaries are those of one window, not of the whole text:
+# a 1 GiB resident corpus otherwise asks ~33 GB of temporaries on a 16 GB
+# chip, and the TPU compile time of the sparse routes' gathers grows with
+# the window (~80 s at 16 MiB, seconds at 1 MiB).  A multiple of
+# EPSMC_BETA, so every window keeps the global block phase.
+SEGMENT_BYTES = 1 << 20
 
 # Fingerprint constants live in packing.py next to the mixing primitives;
 # the private aliases keep existing importers (approx.relaxed, the Pallas
@@ -125,15 +133,20 @@ _DEFAULT_REC = Recorder(enabled=False, fence=False, sinks=(logging_sink(_LOG),))
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class TextIndex:
-    """Pattern-independent view of a (B, n) batch of padded texts."""
+    """Pattern-independent view of a (B, n) batch of padded texts.
 
-    text: jnp.ndarray      # (B, n) uint8
+    The text bytes are not stored: byte i is the low byte of ``packed[i]``,
+    and ``text`` derives them where a matcher reads bytes (a fused
+    elementwise op under jit).  On the TPU a (1, n) uint8 array is laid out
+    four rows to a 32-bit sublane word, so a stored text row would cost
+    4 bytes per corpus byte, as much as ``packed`` itself."""
+
     packed: jnp.ndarray    # (B, n) uint32 — LE-packed 4-gram per position
     block_fp: jnp.ndarray  # (B, n // beta) int32 — aligned beta-block k-bit fps
     lengths: jnp.ndarray   # (B,) int32 — true byte length of each row
 
     def tree_flatten(self):
-        return (self.text, self.packed, self.block_fp, self.lengths), ()
+        return (self.packed, self.block_fp, self.lengths), ()
 
     @classmethod
     def tree_unflatten(cls, aux, children):
@@ -141,12 +154,17 @@ class TextIndex:
         return cls(*children)
 
     @property
+    def text(self) -> jnp.ndarray:
+        """(B, n) uint8 text bytes, derived from ``packed``."""
+        return (self.packed & jnp.uint32(0xFF)).astype(jnp.uint8)
+
+    @property
     def batch(self) -> int:
-        return self.text.shape[0]
+        return self.packed.shape[0]
 
     @property
     def n(self) -> int:
-        return self.text.shape[1]
+        return self.packed.shape[1]
 
 
 def build_index(
@@ -178,10 +196,8 @@ def build_index(
         lengths = jnp.full((B,), n, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     packed = pack_u32(t)
-    nblk = n // beta
-    blocks = t[:, : nblk * beta].reshape(B, nblk, beta)
-    block_fp = hash_blocks(blocks, fingerprint_weights(beta), kbits)
-    return TextIndex(text=t, packed=packed, block_fp=block_fp, lengths=lengths)
+    block_fp = hash_text_blocks(t, fingerprint_weights(beta), kbits)
+    return TextIndex(packed=packed, block_fp=block_fp, lengths=lengths)
 
 
 # ---------------------------------------------------------------------------
@@ -824,7 +840,7 @@ def _b_candidates(
     union-LUT probe (independent of P), compacted to CAND_BLOCK granularity.
     With a FingerprintBank the fingerprint is a shared-prefix read instead
     of a full per-group recomputation."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     if bank is None:
         bank = FingerprintBank(index.packed)
     h = bank.window_fp(plan.m, plan.kbits)  # (B, n)
@@ -852,7 +868,7 @@ def _gather_candidate_rows(
     LUT is denser — see repro.approx.counting).
 
     Returns (rows_packed (nb, C+m-1) u32, bvec (nb,), bstart (nb,), live)."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     C = cblock
     (flat,) = jnp.nonzero(blk_any.reshape(-1), size=budget, fill_value=B * nblk)
     live = flat < B * nblk
@@ -880,7 +896,7 @@ def _b_verify(
 
     Returns (ok (nb, C, P), bvec (nb,), starts (nb, C) with n as the
     out-of-range sentinel)."""
-    n = index.text.shape[1]
+    n = index.packed.shape[1]
     m, C = plan.m, CAND_BLOCK
     rows_packed, bvec, bstart, live = _gather_candidate_rows(
         index, m, blk_any, budget, nblk
@@ -970,7 +986,7 @@ def _b_count_rows_csr(
     gathers CONSECUTIVE rows of the fp-sorted anchor table (the grouped
     gather the CSR sort buys).  O(nb * C * slot_max * nw) — independent of
     P, unlike the flat all-patterns verify's O(nb * C * P * nw)."""
-    B = index.text.shape[0]
+    B = index.packed.shape[0]
     C = starts.shape[1]
     P = plan.n_patterns
     h = row_bank.window_fp(plan.m, plan.kbits)[:, :C]
@@ -1007,7 +1023,7 @@ def _count_b_slot_dense(
     constant of the pattern set — adversarial text can flood the candidate
     stream but cannot change the per-position bound, so collision floods
     degrade to a linear scan instead of the quadratic verify."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     P = plan.n_patterns
     if bank is None:
         bank = FingerprintBank(index.packed)
@@ -1037,7 +1053,7 @@ SPARSE_B_MIN_ELEMS = 8_000_000
 
 
 def _sparse_b_eligible(index: TextIndex, plan: PatternPlan) -> bool:
-    B, n = index.text.shape
+    B, n = index.packed.shape
     return (
         n >= 4 * CAND_BLOCK
         and plan.n_patterns >= 4
@@ -1051,7 +1067,7 @@ def _count_group_b(
     bank: Optional[FingerprintBank] = None,
     end_min=None,
 ) -> jnp.ndarray:
-    B, n = index.text.shape
+    B, n = index.packed.shape
     P = plan.n_patterns
     if plan.slot_off is not None:
         # bucketed (dictionary-scale) plan: sparse CSR verify with the
@@ -1139,7 +1155,7 @@ def _shared_b_route(
     Everything here is host-static (LUT popcounts, slot_max, expected
     density) — the only RUNTIME signal is the measured union block count,
     which the caller compares against ``budget`` inside lax.cond."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     nblk = -(-n // CAND_BLOCK)
     exp, rho = _expected_union_blocks(B, n, plans)
     # Tighter budget than the per-group path's (B*nblk)//3 heavy-tail slack:
@@ -1200,7 +1216,7 @@ def _count_groups_b_shared(
     compare, and union-budget overflow falls back to the dense count for
     ALL shared groups via lax.cond.
     """
-    B, n = index.text.shape
+    B, n = index.packed.shape
     C = CAND_BLOCK
     nblk = -(-n // C)
     max_m = max(p.m for p in plans)
@@ -1317,12 +1333,9 @@ def _c_candidates(index: TextIndex, plan: PatternPlan):
     stride = _epsmc_stride(plan.m, beta)
     step = stride // beta
     if plan.bbits > 0:
-        B_, n_ = index.text.shape
-        nb = n_ // beta
-        blocks = index.text[:, : nb * beta].reshape(B_, nb, beta)
-        ht = hash_blocks(blocks, fingerprint_weights(beta), plan.kbits)[
-            :, ::step
-        ]
+        ht = hash_text_blocks(
+            index.text, fingerprint_weights(beta), plan.kbits
+        )[:, ::step]
     else:
         ht = index.block_fp[:, ::step]  # (B, G) — strided view, no gather
     cand = plan.lut_any[ht]
@@ -1338,7 +1351,7 @@ def _c_candidates(index: TextIndex, plan: PatternPlan):
 def _c_verify(index, plan, ht, cand, stride, noff_used, budget, end_min=None):
     """Verify candidate blocks against all P patterns at the <= stride
     offsets, gated by the LUT's pattern-id payload bitmask."""
-    B, n = index.text.shape
+    B, n = index.packed.shape
     m = plan.m
     G = cand.shape[1]
     (flat,) = jnp.nonzero(cand.reshape(-1), size=budget, fill_value=B * G)
@@ -1395,7 +1408,7 @@ def _c_verify_csr(
     Returns per-entry (ok, pid, b, start) vectors of length
     slot_max * nb for scatter-add/scatter-max joins.
     """
-    B, n = index.text.shape
+    B, n = index.packed.shape
     m = plan.m
     G = cand.shape[1]
     (flat,) = jnp.nonzero(cand.reshape(-1), size=budget, fill_value=B * G)
@@ -1445,7 +1458,7 @@ def _match_group_c(
     end_min=None,
 ) -> jnp.ndarray:
     del bank  # keyed by aligned block fingerprints, not window fingerprints
-    B, n = index.text.shape
+    B, n = index.packed.shape
     P = plan.n_patterns
     if index.block_fp.shape[1] == 0:
         return _dense_c(index, plan, end_min)
@@ -1585,9 +1598,25 @@ def match_many(
 
     ``end_min`` keeps only occurrences ENDING at position >= end_min (the
     streaming seam gate — DESIGN.md §11): equivalent to subtracting a
-    prefix-window scan, fused into the candidate gates of every regime."""
+    prefix-window scan, fused into the candidate gates of every regime.
+
+    A text of n >= SEGMENT_BYTES + overlap columns, n a multiple of
+    SEGMENT_BYTES, is matched window by window (:func:`_match_segmented`)."""
     if not plans:
         return jnp.zeros((index.batch, 0, index.n), jnp.bool_)
+    S = SEGMENT_BYTES
+    if index.n % S == 0 and index.n >= S + _segment_overlap(plans):
+        return _match_segmented(index, plans, k=k, end_min=end_min)
+    return _match_whole(index, plans, k=k, end_min=end_min)
+
+
+def _match_whole(
+    index: TextIndex,
+    plans: Sequence[PatternPlan],
+    *,
+    k: Optional[int],
+    end_min,
+) -> jnp.ndarray:
     bank = FingerprintBank(index.packed)
     outs = []
     for p in plans:
@@ -1624,12 +1653,118 @@ def count_many(
 
     ``end_min`` as in :func:`match_many` (streaming seam gate).
 
+    A text of at least SEGMENT_BYTES + overlap columns (n a multiple of
+    the beta block) is counted window by window (:func:`_count_segmented`).
+
     ``shared=False`` disables the shared-compaction routing and counts every
     group through its own per-group matcher (_COUNT dispatch) — the
     pre-fusion per-group reference path benchmarks and oracle tests pin
     against."""
     if not plans:
         return jnp.zeros((index.batch, 0), jnp.int32)
+    S = SEGMENT_BYTES
+    if index.n % EPSMC_BETA == 0 and index.n >= S + _segment_overlap(plans):
+        return _count_segmented(
+            index, plans, k=k, end_min=end_min, shared=shared
+        )
+    return _count_whole(index, plans, k=k, end_min=end_min, shared=shared)
+
+
+def _segment_overlap(plans: Sequence[PatternPlan]) -> int:
+    """Carried bytes between windows: max_m - 1, rounded up to the beta
+    block so every window starts on a global block boundary."""
+    return -(-(max(p.m for p in plans) - 1) // EPSMC_BETA) * EPSMC_BETA
+
+
+def _window_index(index: TextIndex, st, width: int, cap) -> TextIndex:
+    """Columns [st, st + width) of a resident index (``st`` traced, a
+    multiple of EPSMC_BETA), each row's length cut at ``cap`` columns."""
+    B = index.batch
+    return TextIndex(
+        packed=lax.dynamic_slice(index.packed, (0, st), (B, width)),
+        block_fp=lax.dynamic_slice(
+            index.block_fp, (0, st // EPSMC_BETA), (B, width // EPSMC_BETA)
+        ),
+        lengths=jnp.clip(index.lengths - st, 0, cap),
+    )
+
+
+def _count_segmented(
+    index: TextIndex,
+    plans: Sequence[PatternPlan],
+    *,
+    k: Optional[int],
+    end_min,
+    shared: bool,
+) -> jnp.ndarray:
+    """count_many as a lax.scan over windows of SEGMENT_BYTES new
+    bytes.  Window i holds [a_i - ov, a_i + S) with a_i = i * S (the first
+    is clamped to start at 0, the last to end at n) and counts, through the
+    end_min seam gate, the occurrences that END in [a_i, a_i + S): each
+    exactly once, as in the streaming scanner (DESIGN.md §11).  The windows
+    are slices of the resident packed view and block fingerprints; nothing
+    is recomputed."""
+    B, n = index.batch, index.n
+    S = SEGMENT_BYTES
+    ov = _segment_overlap(plans)
+    W = S + ov
+    P = sum(p.n_patterns for p in plans)
+
+    def window(acc, i):
+        a = i * S
+        st = jnp.clip(a - ov, 0, n - W)
+        # the first window starts at 0, not a_0 - ov: stop it at S too
+        seg = _window_index(index, st, W, jnp.minimum(W, a + S - st))
+        lo = a if end_min is None else jnp.maximum(a, end_min)
+        c = _count_whole(seg, plans, k=k, end_min=lo - st, shared=shared)
+        return acc + c, None
+
+    acc, _ = lax.scan(
+        window, jnp.zeros((B, P), jnp.int32),
+        jnp.arange(-(-n // S), dtype=jnp.int32),
+    )
+    return acc
+
+
+def _match_segmented(
+    index: TextIndex,
+    plans: Sequence[PatternPlan],
+    *,
+    k: Optional[int],
+    end_min,
+) -> jnp.ndarray:
+    """match_many as a loop over windows of SEGMENT_BYTES starts
+    (n a multiple of it).  Window i holds [a_i, a_i + S + ov) with
+    a_i = i * S (the last clamped to end at n) and contributes its mask
+    columns for the starts in [a_i, a_i + S); every occurrence starting
+    there fits in the window, since ov >= max_m - 1."""
+    B, n = index.batch, index.n
+    S = SEGMENT_BYTES
+    ov = _segment_overlap(plans)
+    W = S + ov
+
+    def window(i, out):
+        a = i * S
+        st = jnp.minimum(a, n - W)
+        seg = _window_index(index, st, W, W)
+        lo = None if end_min is None else end_min - st
+        mask = _match_whole(seg, plans, k=k, end_min=lo)
+        cols = lax.dynamic_slice_in_dim(mask, a - st, S, axis=2)
+        return lax.dynamic_update_slice_in_dim(out, cols, a, axis=2)
+
+    P = sum(p.n_patterns for p in plans)
+    out = jnp.zeros((B, P, n), jnp.bool_)
+    return lax.fori_loop(0, n // S, window, out)
+
+
+def _count_whole(
+    index: TextIndex,
+    plans: Sequence[PatternPlan],
+    *,
+    k: Optional[int],
+    end_min,
+    shared: bool,
+) -> jnp.ndarray:
     bank = FingerprintBank(index.packed)
     outs: List[Any] = [None] * len(plans)
     # Exact EPSMb groups on the sparse path: count them together through
@@ -1689,7 +1824,7 @@ def route_probe(
     a diagnostic, not a hot-path call.
     """
     rec = _DEFAULT_REC if recorder is None else recorder
-    B, n = index.text.shape
+    B, n = index.packed.shape
     C = CAND_BLOCK
     nblk = -(-n // C)
     shared_plans = [

@@ -155,3 +155,24 @@ def hash_blocks(blocks_u8: jnp.ndarray, weights: jnp.ndarray, kbits: int) -> jnp
         preferred_element_type=jnp.int32,
     )
     return (h & ((1 << kbits) - 1)).astype(jnp.int32)
+
+
+def hash_text_blocks(
+    text_u8: jnp.ndarray, weights: jnp.ndarray, kbits: int
+) -> jnp.ndarray:
+    """:func:`hash_blocks` of the aligned beta-byte blocks of (..., n) text,
+    (..., n // beta) int32, without a (..., beta) trailing axis.
+
+    Built from beta strided slices of the text instead of a reshape to
+    (..., n // beta, beta): on the TPU a minor axis of beta = 8 is padded
+    to a full 128-lane tile, which makes the reshaped operand 16x larger
+    than the text (64 GiB for a 1 GiB corpus).  The int32 sums wrap mod
+    2^32 in any order, so the result equals :func:`hash_blocks` bit for bit.
+    """
+    beta = int(weights.shape[0])
+    nb = text_u8.shape[-1] // beta
+    h = jnp.zeros(text_u8.shape[:-1] + (nb,), jnp.int32)
+    for j in range(beta):
+        col = text_u8[..., j : nb * beta : beta].astype(jnp.int32)
+        h = h + col * weights[j]
+    return h & ((1 << kbits) - 1)

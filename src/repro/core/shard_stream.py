@@ -555,7 +555,10 @@ class ShardedStreamScanner:
             is_retryable=self.is_retryable, backoff=self.backoff,
             recorder=self.rec, label=lane,
         )
-        self.rec.event("range_done", lane=lane, origin=i, start=s, stop=e)
+        self.rec.event(
+            "range_done", lane=lane, origin=i, start=s, stop=e,
+            device=str(sc.device),
+        )
         self.dispatch_count += sc.dispatch_count
         return out
 
@@ -691,7 +694,7 @@ class ShardedStreamScanner:
             )
             self.rec.event(
                 "range_done", lane=lane_name, origin=item.origin,
-                start=item.start, stop=item.stop,
+                start=item.start, stop=item.stop, device=str(sc.device),
             )
             with lock:
                 self.dispatch_count += sc.dispatch_count

@@ -2,11 +2,12 @@
 texts (DESIGN.md §9).
 
 The resident engine (core/engine.py) wants the whole corpus on device —
-``build_index`` materializes text + packed + block_fp for the full (B, n)
-batch, ~9 bytes of device memory per byte of input.  That blocks the
-ROADMAP's grep/log-scan/pipeline-filter workloads the moment a corpus
-outgrows the device.  This module answers the same count/any/positions
-queries EXACTLY over arbitrarily long inputs in O(chunk) device memory:
+``build_index`` materializes packed + block_fp for the full (B, n) batch,
+4.5 bytes of device memory per byte of input (4 more while it builds).
+That blocks the ROADMAP's grep/log-scan/pipeline-filter workloads the
+moment a corpus outgrows the device.  This module answers the same
+count/any/positions queries EXACTLY over arbitrarily long inputs in
+O(chunk) device memory:
 
   * :class:`StreamScanner` re-chunks any byte source (bytes, arrays, files,
     iterables of chunks) into fixed-capacity windows, carries an
@@ -126,8 +127,8 @@ def auto_chunk_bytes(
       * memory ceiling — the streaming working set is ~9.5 device bytes per
         streamed byte (StreamScanner.device_bytes_per_chunk), so the chunk
         must keep that working set inside a fraction of the device's free
-        memory (``memory_stats`` when the backend reports it, a conservative
-        512 MiB budget otherwise — CPU backends are host-RAM-backed).
+        memory (``memory_stats``; the CPU backend reports none and gets a
+        512 MiB budget, any other backend that reports none raises).
 
     The result is clamped to [MIN_CHUNK_BYTES, MAX_CHUNK_BYTES] and rounded
     to the EPSMc beta block.
@@ -135,17 +136,18 @@ def auto_chunk_bytes(
     dev = device
     if dev is None:
         dev = jax.local_devices()[0]
-    stats = {}
-    try:
-        stats = dev.memory_stats() or {}
-    except Exception:  # backends without memory introspection
-        stats = {}
+    stats = dev.memory_stats() or {}
     limit = stats.get("bytes_limit")
     if limit:
         free = max(int(limit) - int(stats.get("bytes_in_use", 0)), limit // 8)
         budget = free // 4
+    elif dev.platform == "cpu":
+        budget = 512 << 20  # host-RAM-backed: no device limit to read
     else:
-        budget = 512 << 20
+        raise RuntimeError(
+            f"{dev.platform} device {dev} reports no memory limit "
+            f"(memory_stats() = {stats!r}); pass chunk_bytes explicitly"
+        )
     mem_cap = budget // 10  # ~9.5 working-set bytes per streamed byte
     floor = int(
         _dispatch_overhead_s() / overhead_frac * assumed_gbps * 1e9

@@ -1,5 +1,6 @@
-"""Distribution substrate: sharding specs, shard_map compat, fault tolerance,
-gradient compression, and the DLRM-style embedding exchange.
+"""Distribution substrate: sharding specs, cross-device and cross-process
+collective helpers (compat.py), fault tolerance, gradient compression, and
+the DLRM-style embedding exchange.
 
 Modules here are imported by the launchers (launch/cells.py, launch/train.py)
 and by the training loop; they contain no model code.

@@ -1,12 +1,6 @@
-"""Version shims for jax APIs that moved between releases, and the small
-collective helpers the sharded-stream merge rides on.
-
-`jax.shard_map` (with its `check_vma` flag) only exists on newer jax; on the
-0.4.x line the implementation lives in `jax.experimental.shard_map` and the
-replication check is spelled `check_rep`.  `jax.make_mesh` only exists from
-0.4.35.  Everything in this repo goes through these wrappers so the call
-sites stay written against the new API — and the CI jax-version matrix
-(oldest supported pin / latest) exercises both branches of every shim.
+"""Collective helpers the sharded-stream merge rides on: a cross-device
+sum of per-shard accumulators, and host-array sum / ragged all-gather across
+jax.distributed processes.
 """
 
 from __future__ import annotations
@@ -16,76 +10,8 @@ from typing import List, Sequence
 import numpy as np
 
 import jax
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """`jax.shard_map` with graceful fallback to jax.experimental.shard_map."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_vma=check_vma,
-            )
-        except TypeError:  # newer-but-not-newest jax: flag still called check_rep
-            return jax.shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma,
-            )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-    )
-
-
-def axis_size(axis_name) -> int:
-    """`lax.axis_size` inside shard_map/pmap bodies, on any jax version.
-
-    On jax without `lax.axis_size`, `lax.psum(1, name)` folds to the static
-    axis size (a Python int), which is what the ppermute builders need."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """`compiled.cost_analysis()` returns a per-partition list on jax 0.4.x
-    and a flat dict on newer jax; normalize to a dict (first partition)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
-def make_mesh(shape: Sequence[int], axis_names, *, devices=None):
-    """`jax.make_mesh` (0.4.35+) with a manual-Mesh fallback for older jax,
-    plus an explicit `devices` override the shard-stream entrypoint uses to
-    build a mesh over a device SUBSET (jax.make_mesh always takes all)."""
-    shape = tuple(int(s) for s in shape)
-    if devices is None and hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, tuple(axis_names))
-    from jax.sharding import Mesh
-
-    devs = list(jax.devices() if devices is None else devices)
-    n = int(np.prod(shape))
-    if len(devs) < n:
-        raise ValueError(f"mesh {shape} needs {n} devices, have {len(devs)}")
-    arr = np.empty(n, dtype=object)
-    for i, d in enumerate(devs[:n]):
-        arr[i] = d
-    return Mesh(arr.reshape(shape), tuple(axis_names))
-
-
-def _device_of(x):
-    """The single device a committed jax.Array lives on (API moved: .devices()
-    set on newer jax, .device() method on the early 0.4 line)."""
-    devs = getattr(x, "devices", None)
-    if callable(devs):
-        got = devs()
-        return next(iter(got)) if not hasattr(got, "device_kind") else got
-    return x.device()  # pragma: no cover - ancient jax
+from jax.sharding import Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 
 @jax.jit
@@ -105,16 +31,13 @@ def sum_across_devices(parts: Sequence[jax.Array]) -> np.ndarray:
         raise ValueError("sum_across_devices needs at least one part")
     per_dev: dict = {}
     for p in parts:
-        d = _device_of(p)
+        (d,) = p.devices()
         acc = per_dev.get(d)
         per_dev[d] = p if acc is None else acc + p
     vals: List[jax.Array] = list(per_dev.values())
     if len(vals) == 1:
         return np.asarray(jax.device_get(vals[0]))
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    mesh = make_mesh((len(vals),), ("shard",), devices=list(per_dev))
+    mesh = Mesh(np.asarray(list(per_dev)), ("shard",))
     shape = (len(vals),) + tuple(vals[0].shape)
     stacked = jax.make_array_from_single_device_arrays(
         shape, NamedSharding(mesh, P("shard")), [v[None] for v in vals]

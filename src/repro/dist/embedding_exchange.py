@@ -23,11 +23,10 @@ from __future__ import annotations
 
 from typing import Sequence, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-
-from repro.dist.compat import axis_size, shard_map
 
 AxisNames = Union[str, Tuple[str, ...]]
 
@@ -44,7 +43,7 @@ def make_alltoall_lookup(
     batch_spec = batch_axes[0] if len(batch_axes) == 1 else batch_axes
 
     def local_lookup(table_shard, ids):
-        k = axis_size(table_axis)
+        k = lax.axis_size(table_axis)
         me = lax.axis_index(table_axis)
         rows = table_shard.shape[0]  # rows per shard (V // k)
         n = ids.shape[0]
@@ -83,7 +82,7 @@ def make_alltoall_lookup(
 
         return lax.cond(~overflow, a2a_path, psum_path, operand=None)
 
-    return shard_map(
+    return jax.shard_map(
         local_lookup,
         mesh=mesh,
         in_specs=(P(table_axis, None), P(batch_spec)),

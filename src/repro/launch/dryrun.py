@@ -21,7 +21,6 @@ from repro.analysis import roofline  # noqa: E402
 from repro.analysis.hlo_collectives import collective_sites, collective_stats  # noqa: E402
 from repro.analysis.jaxpr_cost import step_cost  # noqa: E402
 from repro.configs.registry import all_cells, get_arch  # noqa: E402
-from repro.dist.compat import cost_analysis_dict  # noqa: E402
 from repro.launch.cells import build_cell  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 
@@ -73,7 +72,7 @@ def run_cell(arch_id: str, shape_id: str, multi_pod: bool, outdir: Path, *, mesh
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
 
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     # XLA cost analysis counts while/scan bodies once (verified; see

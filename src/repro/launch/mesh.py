@@ -1,7 +1,8 @@
 """Mesh construction and the shard-stream cluster entrypoint.  FUNCTIONS,
 not module-level constants — importing this module never touches jax device
-state.  All mesh building goes through ``repro.dist.compat.make_mesh`` so
-the same code runs on the 0.4.x line (no ``jax.make_mesh``) and on latest.
+state.  Meshes use Auto axes, which the sharding rules'
+``with_sharding_constraint`` calls require (``jax.make_mesh`` defaults to
+Explicit axes).
 """
 
 from __future__ import annotations
@@ -9,15 +10,14 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-from repro.dist import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 chips per pod; 2 pods when multi_pod (512 chips total)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(axis_names=("data", "model")):
@@ -25,7 +25,7 @@ def make_local_mesh(axis_names=("data", "model")):
     Puts all devices on the first axis."""
     n = len(jax.devices())
     shape = (n,) + (1,) * (len(axis_names) - 1)
-    return compat.make_mesh(shape, axis_names)
+    return jax.make_mesh(shape, axis_names, (AxisType.Auto,) * len(axis_names))
 
 
 # (the sharded-stream count merge builds its own 1-D device mesh inline in

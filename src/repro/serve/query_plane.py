@@ -113,19 +113,27 @@ class CorpusEntry:
     corpus_id: str
     index: engine.TextIndex
     digest: str          # sha1 of the raw bytes — result-cache identity
-    nbytes: int          # measured device bytes (text+packed+block_fp+lengths)
+    nbytes: int          # measured device bytes (packed+block_fp+lengths)
     raw_len: int         # true byte length (before pow2 padding)
 
 
 def _index_nbytes(index: engine.TextIndex) -> int:
     return int(
-        index.text.nbytes + index.packed.nbytes
-        + index.block_fp.nbytes + index.lengths.nbytes
+        index.packed.nbytes + index.block_fp.nbytes + index.lengths.nbytes
     )
 
 
 def _pow2_ceil(n: int) -> int:
     return 1 << max(0, int(n - 1).bit_length())
+
+
+@jax.jit
+def _resident_index(text: jnp.ndarray, length: jnp.ndarray):
+    """One-row TextIndex of an (n,) text, built in one compiled program: the
+    text crosses to the device as a flat row (a (1, n) uint8 array takes
+    4 bytes per byte on the TPU), and no per-op temporary of an eager build
+    is ever live at once."""
+    return engine.build_index(text[None, :], length[None])
 
 
 class CorpusCache:
@@ -156,9 +164,9 @@ class CorpusCache:
             raise ValueError("corpus must be non-empty")
         arr = np.frombuffer(raw, np.uint8)
         n = _pow2_ceil(arr.size)
-        padded = np.zeros((1, n), np.uint8)
-        padded[0, : arr.size] = arr
-        index = engine.build_index(padded, np.array([arr.size], np.int32))
+        padded = np.zeros(n, np.uint8)
+        padded[: arr.size] = arr
+        index = _resident_index(padded, np.int32(arr.size))
         jax.block_until_ready(index.packed)
         return CorpusEntry(
             corpus_id=str(corpus_id),
@@ -175,7 +183,7 @@ class CorpusCache:
         self._entries[entry.corpus_id] = entry
         self.rec.event(
             "corpus_load", corpus=entry.corpus_id, nbytes=entry.nbytes,
-            raw_len=entry.raw_len, n=entry.index.text.shape[1],
+            raw_len=entry.raw_len, n=entry.index.n,
         )
         self._evict_over_budget(keep=entry.corpus_id)
         return entry
@@ -478,7 +486,12 @@ class QueryPlane:
         for bkey, batch in list(self._batches.items()):
             self._flush_batch(bkey, batch)
         while self._tasks:
-            await asyncio.gather(*list(self._tasks), return_exceptions=True)
+            # take the tasks out here: gather over tasks that are already
+            # done returns without yielding, so their discard callbacks
+            # would never run and this loop would spin forever
+            tasks = list(self._tasks)
+            self._tasks.difference_update(tasks)
+            await asyncio.gather(*tasks, return_exceptions=True)
 
     async def close(self) -> None:
         """Drain in-flight work and release the dispatch thread."""

@@ -48,3 +48,18 @@ def test_periodic_patterns_all_baselines(rng):
             np.testing.assert_array_equal(
                 np.asarray(fn(t, p)), oracle, err_msg=f"{name} m={m}"
             )
+
+
+@pytest.mark.parametrize("sigma", [2, 4, 26])
+def test_find_all_overlapping_equals_naive(rng, sigma):
+    """find_all, the host reference of the service and stream checks,
+    reports every start, overlapping ones included (bytes.count would
+    not), exactly as the scalar oracle."""
+    t = make_text(rng, 3000, sigma)
+    buf = t.tobytes()
+    for m in (1, 2, 3, 7, 16, 33):
+        p = t[100 : 100 + m].tobytes()
+        want = np.flatnonzero(baselines.naive_np(t, t[100 : 100 + m]))
+        np.testing.assert_array_equal(baselines.find_all(buf, p), want)
+    assert baselines.find_all(b"aaaa", b"aa").tolist() == [0, 1, 2]
+    assert baselines.find_all(b"abc", b"x").size == 0

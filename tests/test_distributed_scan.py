@@ -12,13 +12,12 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import baselines, distributed
-from repro.dist.compat import make_mesh
 
 from conftest import make_text
 
 
 def test_single_device_mesh(rng):
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     t = make_text(rng, 1024, 4)
     p = t[100:108].copy()
     f = distributed.make_distributed_find(mesh, "data")
@@ -34,13 +33,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.core import distributed, baselines
-from repro.dist.compat import make_mesh
 
 rng = np.random.RandomState(42)
 n = 8 * 512
 t = rng.randint(0, 4, size=n).astype(np.uint8)
 
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",))
 for m in [1, 2, 9, 17, 32]:
     s = rng.randint(0, n - m)
     p = t[s:s+m].copy()
@@ -51,7 +49,7 @@ for m in [1, 2, 9, 17, 32]:
     c = distributed.make_distributed_count(mesh, "data")
     assert int(c(jnp.asarray(t), jnp.asarray(p))) == oracle.sum(), ("count", m)
 
-mesh2 = make_mesh((2, 4), ("pod", "data"))
+mesh2 = jax.make_mesh((2, 4), ("pod", "data"))
 for m in [3, 9, 20]:
     s = rng.randint(0, n - m)
     p = t[s:s+m].copy()

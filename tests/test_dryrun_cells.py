@@ -14,9 +14,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from pathlib import Path
 from repro.launch.dryrun import run_cell
-from repro.dist.compat import make_mesh
+from jax.sharding import AxisType
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
 out = Path("/tmp/dryrun_cells_test")
 cells = [
     ("smollm-135m", "train_4k", "default"),

@@ -31,9 +31,8 @@ import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.train import checkpoint as ckpt
-from repro.dist.compat import make_mesh
 
-mesh = make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"))
 tree = {{
     "w": jnp.zeros((64, 16)),
     "opt": {{"m": jnp.zeros((64, 16)), "step": jnp.int32(0)}},

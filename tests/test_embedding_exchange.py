@@ -11,10 +11,9 @@ SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
-from repro.dist.compat import make_mesh
 from repro.dist.embedding_exchange import make_alltoall_lookup
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"))
 rng = np.random.RandomState(0)
 V, d, n = 4096, 16, 512
 table = rng.randn(V, d).astype(np.float32)

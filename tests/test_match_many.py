@@ -315,3 +315,56 @@ def test_stop_scanner_wraparound_exhaustive(rng):
             and stream[e - len(stop) + 1 : e + 1] == stop
         ]
         assert hits.get(si, []) == want, f"stop {si}"
+
+
+@pytest.mark.parametrize(
+    "segment,length,end_min",
+    [
+        (1 << 10, 1 << 14, None),        # count and match both windowed
+        (1 << 12, (1 << 14) - 13, 777),  # ragged length, seam gate given
+        (4096 + 8, 1 << 14, None),       # n % S != 0: match keeps one pass
+    ],
+)
+def test_windowed_scan_equals_whole_text(
+    rng, monkeypatch, segment, length, end_min
+):
+    """Texts longer than SEGMENT_BYTES are counted and matched window by
+    window over the resident index; every occurrence, including those
+    across window seams and at the clamped first and last windows, counts
+    once, as in the one-pass scan."""
+    n = 1 << 14
+    t = make_text(rng, n, 4)
+    pats = _mixed_patterns(rng, t, (2, 3, 5, 8, 12, 16, 20, 32))
+    pats += [t[s : s + 8].copy() for s in range(0, n - 8, 997)]  # P >= 8 group
+    plans = engine.compile_patterns(pats)
+    idx = engine.build_index(t[None], np.array([length], np.int32))
+    whole_c = np.asarray(engine.count_many(idx, plans, end_min=end_min))
+    whole_m = np.asarray(engine.match_many(idx, plans, end_min=end_min))
+    assert whole_c.sum() > 0
+    monkeypatch.setattr(engine, "SEGMENT_BYTES", segment)
+    np.testing.assert_array_equal(
+        np.asarray(engine.count_many(idx, plans, end_min=end_min)), whole_c
+    )
+    np.testing.assert_array_equal(
+        np.asarray(engine.match_many(idx, plans, end_min=end_min)), whole_m
+    )
+
+
+def test_text_index_derives_text_from_packed(rng):
+    """The index keeps no text row: its bytes are the low bytes of the
+    packed view, and the block fingerprints equal hash_blocks of the
+    reshaped (n // beta, beta) blocks."""
+    from repro.core.packing import fingerprint_weights, hash_blocks
+
+    t = make_text(rng, 1003, 256)
+    idx = engine.build_index(t)
+    assert len(jax.tree_util.tree_leaves(idx)) == 3
+    np.testing.assert_array_equal(np.asarray(idx.text)[0], t)
+    nb = len(t) // engine.EPSMC_BETA
+    blocks = jnp.asarray(t[: nb * engine.EPSMC_BETA].reshape(nb, -1))
+    want = hash_blocks(
+        blocks, fingerprint_weights(engine.EPSMC_BETA), engine.ENGINE_KBITS
+    )
+    np.testing.assert_array_equal(
+        np.asarray(idx.block_fp)[0], np.asarray(want)
+    )

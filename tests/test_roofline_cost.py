@@ -9,7 +9,6 @@ import jax.numpy as jnp
 
 from repro.analysis.hlo_collectives import collective_stats
 from repro.analysis.jaxpr_cost import step_cost
-from repro.dist.compat import make_mesh
 from repro.analysis.roofline import collective_bytes, roofline_terms
 
 
@@ -20,11 +19,9 @@ def test_walker_matches_unrolled_hlo():
         h, _ = jax.lax.scan(body, x, None, length=16, unroll=16)
         return h
 
-    from repro.dist.compat import cost_analysis_dict
-
     args = (jax.ShapeDtypeStruct((128, 256), jnp.float32),
             jax.ShapeDtypeStruct((256, 256), jnp.float32))
-    hlo_flops = cost_analysis_dict(jax.jit(f).lower(*args).compile())["flops"]
+    hlo_flops = jax.jit(f).lower(*args).compile().cost_analysis()["flops"]
     est = step_cost(f, *args)
     assert abs(est["flops"] - hlo_flops) / hlo_flops < 0.05
 
@@ -65,7 +62,7 @@ def test_collective_parser_multiplies_while_trips():
     """Collectives inside a scanned body must be scaled by trip count."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     L = 8
 
     def f(x, w):
@@ -111,8 +108,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.analysis.hlo_collectives import collective_stats
-from repro.dist.compat import make_mesh
-mesh = make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",))
 L = 8
 
 def f(x, w):

@@ -27,7 +27,7 @@ from repro.core.shard_stream import (
     source_total_bytes,
 )
 from repro.core.stream import Compressed, StreamScanner
-from repro.dist.compat import make_mesh, sum_across_devices
+from repro.dist.compat import sum_across_devices
 from repro.dist.fault_tolerance import InjectedFault
 from repro.dist.sharding import make_stream_shard_spec, range_partition
 
@@ -340,7 +340,7 @@ def test_distributed_scan_inprocess_mesh(rng):
         pytest.skip("needs 8 devices (CI multihost job)")
     from repro.core import baselines, distributed
 
-    mesh = make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",))
     t = make_text(rng, 8 * 512, 4)
     for m in (2, 9, 17):
         p = t[40 : 40 + m].copy()
@@ -354,10 +354,18 @@ def test_distributed_scan_inprocess_mesh(rng):
 
 
 def test_compat_make_mesh_fallback_branch():
-    """The manual-Mesh branch (pre-0.4.35 jax, or an explicit device subset)
-    builds the same mesh shape as jax.make_mesh."""
+    """sum_across_devices builds its mesh over only the devices that hold
+    parts: parts on a subset of the devices (here two of each device in
+    use, and one device alone) merge to the host sum."""
     devs = jax.devices()
-    mesh = make_mesh((1,), ("data",), devices=devs[:1])
-    assert mesh.axis_names == ("data",) and mesh.shape["data"] == 1
+    parts = [
+        jax.device_put(np.full(5, i + 1, np.int32), devs[i % len(devs)])
+        for i in range(2 * min(len(devs), 2))
+    ]
+    want = sum(np.asarray(p) for p in parts)
+    np.testing.assert_array_equal(sum_across_devices(parts), want)
+    np.testing.assert_array_equal(
+        sum_across_devices([jax.device_put(p, devs[0]) for p in parts]), want
+    )
     with pytest.raises(ValueError):
-        make_mesh((len(devs) + 1,), ("data",), devices=devs)
+        sum_across_devices([])

@@ -197,6 +197,34 @@ def test_auto_chunk_bytes_resolved_and_exact(rng):
     np.testing.assert_array_equal(sc.count_many(text), want)
 
 
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform = platform
+        self._stats = stats
+
+    def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
+        return self._stats
+
+
+@pytest.mark.parametrize(
+    "stats", [None, {}, RuntimeError("memory_stats unsupported")]
+)
+def test_auto_chunk_bytes_needs_memory_stats_off_cpu(stats):
+    """Only the CPU backend may size chunks without a device memory limit:
+    elsewhere a missing limit or a failing memory_stats() raises instead of
+    assuming the 512 MiB budget."""
+    from repro.core.stream import auto_chunk_bytes
+
+    with pytest.raises(RuntimeError):
+        auto_chunk_bytes(device=_FakeDevice("tpu", stats))
+    if not isinstance(stats, Exception):
+        assert auto_chunk_bytes(device=_FakeDevice("cpu", stats)) > 0
+    limit = {"bytes_limit": 16 << 30, "bytes_in_use": 0}
+    assert auto_chunk_bytes(device=_FakeDevice("tpu", limit)) > 0
+
+
 def test_one_dispatch_per_chunk_and_bounded_window(rng):
     text = make_text(rng, 10_000, 4)
     plans = engine.compile_patterns([text[50:58].copy(), text[300:316].copy()])

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 
 from repro.data import corpus
 from repro.data.pipeline import BOS, LMDataPipeline
-from repro.dist.compat import make_mesh
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
 
@@ -101,7 +100,7 @@ def test_elastic_restore_resharding(tmp_path):
     """Checkpoint saved unsharded restores onto a sharded layout."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     ckpt.save(tree, tmp_path, step=5)
     sh = {"w": NamedSharding(mesh, P("data", None))}
@@ -213,17 +212,16 @@ def test_gradient_compression_accuracy():
     """int8+EF quantized psum ~= exact psum, and EF kills the bias over steps."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import make_mesh, shard_map
     from repro.dist.compression import quantized_psum, zeros_residuals
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",))
     g = {"w": jnp.asarray(np.random.RandomState(0).randn(128, 8), jnp.float32)}
     res = zeros_residuals(g)
 
     def f(g, r):
         return quantized_psum(g, r, "data")
 
-    out, new_res = shard_map(
+    out, new_res = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
     )(g, res)
     rel = np.abs(np.asarray(out["w"]) - np.asarray(g["w"])).max() / np.abs(
