@@ -4,7 +4,7 @@ what would not fit or lower there.  Each compile takes seconds here.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and the suite's workers all
-import this file (on-chip-measurement guide, section 2)."""
+import this file."""
 
 from __future__ import annotations
 
