@@ -19,6 +19,11 @@ Phases (one process, data generated from --seed, nothing downloaded):
      a byte the corpus never uses and is planted across window seams, so
      the expected counts and positions are the plants themselves.
 
+With --chips 4 only the sharded stream scan runs: a ShardedStreamScanner
+with one shard per chip and the one-chip StreamScanner count the same
+stream, whose plants also cross every shard boundary and every shard's own
+window seams; both must equal the plants.
+
 The last line of standard output is ``{"ok": true, "device": {...}}``; it is
 printed only when every check passed on a TPU.  Every earlier line is smoke
 output (sizes, wall times, compile time, memory), not a benchmark number.
@@ -28,6 +33,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import bisect
 import json
 import sys
 import time
@@ -238,26 +244,56 @@ def stream_queries(seed: int) -> list:
     return qs
 
 
-def plant_sites(sc: StreamScanner, total: int, seed: int, queries) -> list:
-    """(start, query) plants across the scanner's window seams, at every
-    phase, and at random interior positions, at least 64 bytes apart.
-    Window w >= 1 takes in new bytes from overlap + w * step on."""
+def window_seams(sc: StreamScanner, start: int, stop: int) -> list:
+    """Positions in [start, stop) where a window of ``sc`` begins its new
+    bytes, for a scan of that range that carries an overlap prefix when
+    start > 0, as each shard of a ShardedStreamScanner does.  From 0, the
+    first window takes overlap + step new bytes, each later one step."""
+    first = start if start > 0 else sc.overlap + sc.step_bytes
+    return list(range(first, stop, sc.step_bytes))
+
+
+def plant_sites(seam_groups, total: int, seed: int, queries) -> list:
+    """Sorted (start, query) plants, at least 64 bytes apart: one across
+    each seam of each group, the seams of a group taking the query phases
+    in turn, then random interior positions.  An earlier group keeps its
+    plant where a later one would come too close."""
     rng = np.random.default_rng([seed, 3])
-    sites = []
-    for w in range(1, total // sc.step_bytes):
-        q = w % len(queries)
-        seam = sc.overlap + w * sc.step_bytes
-        phase = (w // len(queries)) % (len(queries[q]) - 1)
-        sites.append((seam - 1 - phase, q))
-    for s in rng.integers(0, total - 64, size=max(16, total >> 22)):
-        sites.append((int(s), int(rng.integers(0, len(queries)))))
-    sites.sort()
-    kept, last = [], -(1 << 62)
-    for s, q in sites:
-        if s - last >= 64 and s + 64 <= total:
-            kept.append((s, q))
-            last = s
-    return kept
+    groups = []
+    for seams in seam_groups:
+        group = []
+        for j, seam in enumerate(seams):
+            q = j % len(queries)
+            phase = (j // len(queries)) % (len(queries[q]) - 1)
+            group.append((seam - 1 - phase, q))
+        groups.append(group)
+    groups.append([
+        (int(s), int(rng.integers(0, len(queries))))
+        for s in rng.integers(0, total - 64, size=max(16, total >> 22))
+    ])
+    taken, sites = [], []
+    for group in groups:
+        for s, q in group:
+            i = bisect.bisect_left(taken, s)
+            near = (i < len(taken) and taken[i] - s < 64) or (
+                i > 0 and s - taken[i - 1] < 64
+            )
+            if s >= 0 and s + 64 <= total and not near:
+                taken.insert(i, s)
+                sites.append((s, q))
+    return sorted(sites)
+
+
+def straddling(sites, queries, seams) -> int:
+    """How many of ``seams`` a planted query crosses (starts before the
+    seam, ends at or after it)."""
+    starts = [s for s, _ in sites]
+    hit = 0
+    for b in seams:
+        i = bisect.bisect_left(starts, b) - 1
+        if i >= 0 and starts[i] + len(queries[sites[i][1]]) > b:
+            hit += 1
+    return hit
 
 
 class SeededStream:
@@ -304,9 +340,7 @@ class SeededStream:
 def stream_setup(total: int, seed: int, chunk_bytes):
     queries = stream_queries(seed)
     plans = engine.compile_patterns(queries)
-    sc = StreamScanner(plans, chunk_bytes)
-    sites = plant_sites(sc, total, seed, queries)
-    return queries, plans, sc, SeededStream(total, seed, queries, sites)
+    return queries, plans, StreamScanner(plans, chunk_bytes)
 
 
 def in_query_order(rows, order):
@@ -319,7 +353,12 @@ def in_query_order(rows, order):
 def phase_stream(total: int, *, seed: int) -> None:
     dev = jax.devices()[0]
     log(f"stream: memory_stats before the scan {device_memory(dev)}")
-    queries, _plans, sc, source = stream_setup(total, seed, "auto")
+    queries, _plans, sc = stream_setup(total, seed, "auto")
+    seams = window_seams(sc, 0, total)
+    sites = plant_sites([seams], total, seed, queries)
+    source = SeededStream(total, seed, queries, sites)
+    log(f"stream: plants cross {straddling(sites, queries, seams)} of "
+        f"{len(seams)} window seams")
     log(f"stream: chunk_bytes='auto' chose {sc.chunk_bytes} B "
         f"(window {sc.window_bytes} B, step {sc.step_bytes} B), "
         f"use_kernel=False (the fused XLA step; no Pallas kernel)")
@@ -351,11 +390,30 @@ def phase_sharded(total: int, n_chips: int, *, seed: int) -> None:
     if len(devices) < n_chips:
         raise SystemExit(f"FAIL: {n_chips} chips asked, {len(devices)} found")
     devices = devices[:n_chips]
-    _queries, plans, one, source = stream_setup(total, seed, "auto")
+    queries, plans, one = stream_setup(total, seed, "auto")
     rec = Recorder(enabled=True, fence=False)
     sharded = ShardedStreamScanner(
         plans, n_chips, one.chunk_bytes, devices=devices, recorder=rec
     )
+    # Plants across the shard boundaries first (each shard's overlap prefix
+    # must hand such an occurrence to exactly one shard), then across each
+    # shard's own window seams, then across the one-chip scanner's.
+    ranges = sharded.shard_spec(total).ranges
+    bounds = [a for a, _ in ranges[1:]]
+    shard_seams = [
+        b for a, e in ranges for b in window_seams(one, a, e) if a == 0 or b > a
+    ]
+    one_seams = window_seams(one, 0, total)
+    sites = plant_sites([bounds, shard_seams, one_seams], total, seed, queries)
+    source = SeededStream(total, seed, queries, sites)
+    crossed = straddling(sites, queries, bounds)
+    log(f"sharded: shards {list(ranges)}; plants cross {crossed} of "
+        f"{len(bounds)} shard boundaries, "
+        f"{straddling(sites, queries, shard_seams)} of {len(shard_seams)} "
+        f"shard window seams, {straddling(sites, queries, one_seams)} of "
+        f"{len(one_seams)} one-chip window seams")
+    if crossed != len(bounds):
+        raise SystemExit("FAIL: a shard boundary has no plant across it")
     t0 = time.perf_counter()
     got = sharded.count_many(source, total_bytes=total)
     log(f"sharded: {n_chips} shards, {total} B in "

@@ -9,6 +9,7 @@ packed scan finds a stop sequence in the generated bytes.
 
 import argparse
 import dataclasses
+from pathlib import Path
 
 import jax
 
@@ -20,12 +21,15 @@ from repro.serve.engine import ServeEngine
 from repro.train import checkpoint as ckpt
 from repro.train.optimizer import adamw_init
 
+# Shared by train_lm.py (writes) and serve_lm.py (reads); gitignored.
+CKPT_DIR = Path(__file__).resolve().parents[1] / ".lm_ckpt"
+
 
 def main():
     configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--max-new", type=int, default=48)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(CKPT_DIR))
     ap.add_argument("--temperature", type=float, default=0.8)
     args = ap.parse_args()
 

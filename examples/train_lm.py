@@ -10,6 +10,7 @@ straggler watchdog, and resume-on-restart.
 
 import argparse
 import dataclasses
+from pathlib import Path
 
 import jax
 
@@ -22,6 +23,9 @@ from repro.models import transformer as tf
 from repro.train.loop import TrainConfig, train
 from repro.train.optimizer import AdamWConfig
 
+# Shared by train_lm.py (writes) and serve_lm.py (reads); gitignored.
+CKPT_DIR = Path(__file__).resolve().parents[1] / ".lm_ckpt"
+
 
 def main():
     configure_compile_cache()
@@ -29,7 +33,7 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
+    ap.add_argument("--ckpt-dir", default=str(CKPT_DIR))
     ap.add_argument("--full", action="store_true", help="full 135M config")
     args = ap.parse_args()
 

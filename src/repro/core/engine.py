@@ -1600,12 +1600,11 @@ def match_many(
     streaming seam gate — DESIGN.md §11): equivalent to subtracting a
     prefix-window scan, fused into the candidate gates of every regime.
 
-    A text of n >= SEGMENT_BYTES + overlap columns, n a multiple of
-    SEGMENT_BYTES, is matched window by window (:func:`_match_segmented`)."""
+    A text of at least SEGMENT_BYTES + overlap columns (n a multiple of
+    the beta block) is matched window by window (:func:`_match_segmented`)."""
     if not plans:
         return jnp.zeros((index.batch, 0, index.n), jnp.bool_)
-    S = SEGMENT_BYTES
-    if index.n % S == 0 and index.n >= S + _segment_overlap(plans):
+    if _segmented(index, plans):
         return _match_segmented(index, plans, k=k, end_min=end_min)
     return _match_whole(index, plans, k=k, end_min=end_min)
 
@@ -1662,12 +1661,20 @@ def count_many(
     against."""
     if not plans:
         return jnp.zeros((index.batch, 0), jnp.int32)
-    S = SEGMENT_BYTES
-    if index.n % EPSMC_BETA == 0 and index.n >= S + _segment_overlap(plans):
+    if _segmented(index, plans):
         return _count_segmented(
             index, plans, k=k, end_min=end_min, shared=shared
         )
     return _count_whole(index, plans, k=k, end_min=end_min, shared=shared)
+
+
+def _segmented(index: TextIndex, plans: Sequence[PatternPlan]) -> bool:
+    """Whether count_many/match_many scan ``index`` window by window: its
+    width is a whole number of beta blocks (so every window starts on a
+    block) and holds at least one full window.  This covers the stream
+    scanner's chunks as well as a resident corpus."""
+    n = index.n
+    return n % EPSMC_BETA == 0 and n >= SEGMENT_BYTES + _segment_overlap(plans)
 
 
 def _segment_overlap(plans: Sequence[PatternPlan]) -> int:
@@ -1733,18 +1740,19 @@ def _match_segmented(
     k: Optional[int],
     end_min,
 ) -> jnp.ndarray:
-    """match_many as a loop over windows of SEGMENT_BYTES starts
-    (n a multiple of it).  Window i holds [a_i, a_i + S + ov) with
-    a_i = i * S (the last clamped to end at n) and contributes its mask
-    columns for the starts in [a_i, a_i + S); every occurrence starting
-    there fits in the window, since ov >= max_m - 1."""
+    """match_many as a loop over windows of SEGMENT_BYTES starts.
+    Window i holds [st_i, st_i + S + ov) with st_i = min(a_i, n - S - ov)
+    and a_i = min(i * S, n - S), and writes its mask columns for the
+    starts in [a_i, a_i + S); every occurrence starting there fits in the
+    window, since ov >= max_m - 1.  Where the last window's columns
+    overlap the one before, both write the same values."""
     B, n = index.batch, index.n
     S = SEGMENT_BYTES
     ov = _segment_overlap(plans)
     W = S + ov
 
     def window(i, out):
-        a = i * S
+        a = jnp.minimum(i * S, n - S)
         st = jnp.minimum(a, n - W)
         seg = _window_index(index, st, W, W)
         lo = None if end_min is None else end_min - st
@@ -1754,7 +1762,7 @@ def _match_segmented(
 
     P = sum(p.n_patterns for p in plans)
     out = jnp.zeros((B, P, n), jnp.bool_)
-    return lax.fori_loop(0, n // S, window, out)
+    return lax.fori_loop(0, -(-n // S), window, out)
 
 
 def _count_whole(

@@ -5,8 +5,24 @@ XLA_FLAGS override belongs ONLY to launch/dryrun.py (and subprocesses
 spawned by the multi-device tests), never here.
 """
 
+import os
+from pathlib import Path
+
 import numpy as np
 import pytest
+
+# The checkout that holds these tests: subprocesses run from it and import
+# its src/, never another copy of the repo.
+REPO_ROOT = Path(__file__).resolve().parents[1]
+
+
+def subprocess_env(**extra):
+    """Environment for a test's child python: this checkout's src/ first on
+    PYTHONPATH, plus ``extra`` variables."""
+    path = [str(REPO_ROOT / "src")]
+    if os.environ.get("PYTHONPATH"):
+        path.append(os.environ["PYTHONPATH"])
+    return {**os.environ, "PYTHONPATH": os.pathsep.join(path), **extra}
 
 
 @pytest.fixture(scope="session")

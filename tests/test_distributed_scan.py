@@ -13,7 +13,7 @@ import jax.numpy as jnp
 
 from repro.core import baselines, distributed
 
-from conftest import make_text
+from conftest import REPO_ROOT, make_text, subprocess_env
 
 
 def test_single_device_mesh(rng):
@@ -68,7 +68,7 @@ def test_multi_device_subprocess():
         capture_output=True,
         text=True,
         timeout=600,
-        env={**__import__("os").environ, "PYTHONPATH": "src"},
-        cwd="/root/repo",
+        env=subprocess_env(),
+        cwd=REPO_ROOT,
     )
     assert "DISTRIBUTED_OK" in res.stdout, res.stdout + res.stderr

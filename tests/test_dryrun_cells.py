@@ -2,11 +2,12 @@
 three families (and both LM sharding strategies) on a small fake-device mesh
 in a subprocess (device count locks at first jax init)."""
 
-import os
 import subprocess
 import sys
 
 import pytest
+
+from conftest import REPO_ROOT, subprocess_env
 
 SCRIPT = r"""
 import os
@@ -17,7 +18,7 @@ from repro.launch.dryrun import run_cell
 from jax.sharding import AxisType
 
 mesh = jax.make_mesh((4, 2), ("data", "model"), (AxisType.Auto,) * 2)
-out = Path("/tmp/dryrun_cells_test")
+out = Path(os.environ["DRYRUN_CELLS_OUT"])
 cells = [
     ("smollm-135m", "train_4k", "default"),
     ("smollm-135m", "train_4k", "zero_dp"),
@@ -37,10 +38,10 @@ print("DRYRUN_CELLS_OK")
 
 
 @pytest.mark.slow
-def test_dryrun_cells_subprocess():
+def test_dryrun_cells_subprocess(tmp_path):
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo",
+        env=subprocess_env(DRYRUN_CELLS_OUT=str(tmp_path)), cwd=REPO_ROOT,
     )
     assert "DRYRUN_CELLS_OK" in res.stdout, res.stdout[-2000:] + res.stderr[-3000:]

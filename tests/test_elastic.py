@@ -2,7 +2,6 @@
 onto a DIFFERENT (8 fake device) mesh with re-sharding — the down/up-scale
 path after losing or gaining nodes."""
 
-import os
 import subprocess
 import sys
 
@@ -12,6 +11,8 @@ import pytest
 import jax.numpy as jnp
 
 from repro.train import checkpoint as ckpt
+
+from conftest import REPO_ROOT, subprocess_env
 
 
 @pytest.mark.slow
@@ -54,6 +55,6 @@ print("ELASTIC_OK")
     res = subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo",
+        env=subprocess_env(), cwd=REPO_ROOT,
     )
     assert "ELASTIC_OK" in res.stdout, res.stdout + res.stderr

@@ -1,11 +1,12 @@
 """All-to-all embedding exchange: exactness (incl. skew overflow fallback)
 on a multi-device subprocess mesh."""
 
-import os
 import subprocess
 import sys
 
 import pytest
+
+from conftest import REPO_ROOT, subprocess_env
 
 SCRIPT = r"""
 import os
@@ -42,6 +43,6 @@ def test_alltoall_exchange_subprocess():
     res = subprocess.run(
         [sys.executable, "-c", SCRIPT],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo",
+        env=subprocess_env(), cwd=REPO_ROOT,
     )
     assert "EXCHANGE_OK" in res.stdout, res.stdout + res.stderr

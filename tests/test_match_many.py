@@ -318,25 +318,29 @@ def test_stop_scanner_wraparound_exhaustive(rng):
 
 
 @pytest.mark.parametrize(
-    "segment,length,end_min",
+    "segment,n,length,end_min,k",
     [
-        (1 << 10, 1 << 14, None),        # count and match both windowed
-        (1 << 12, (1 << 14) - 13, 777),  # ragged length, seam gate given
-        (4096 + 8, 1 << 14, None),       # n % S != 0: match keeps one pass
+        (1 << 10, 1 << 14, 1 << 14, None, 0),          # every window full
+        (1 << 12, 1 << 14, (1 << 14) - 13, 777, 0),    # ragged length, gate
+        (4096 + 8, 1 << 14, 1 << 14, None, 0),         # n % S != 0
+        # a stream chunk: prev_ov carried bytes + chunk, n % S != 0, the
+        # seam gate at prev_ov (core/stream.py's fused count step)
+        (1 << 12, 3 * 4096 + 1000, 3 * 4096 + 1000 - 5, 32, 0),
+        (1 << 12, 3 * 4096 + 1000, 3 * 4096 + 1000 - 5, 32, 1),  # k > 0
+        (1 << 10, 1 << 14, 1 << 14, None, 1),
     ],
 )
 def test_windowed_scan_equals_whole_text(
-    rng, monkeypatch, segment, length, end_min
+    rng, monkeypatch, segment, n, length, end_min, k
 ):
     """Texts longer than SEGMENT_BYTES are counted and matched window by
     window over the resident index; every occurrence, including those
     across window seams and at the clamped first and last windows, counts
-    once, as in the one-pass scan."""
-    n = 1 << 14
+    once, as in the one-pass scan, exact and with a mismatch budget."""
     t = make_text(rng, n, 4)
     pats = _mixed_patterns(rng, t, (2, 3, 5, 8, 12, 16, 20, 32))
     pats += [t[s : s + 8].copy() for s in range(0, n - 8, 997)]  # P >= 8 group
-    plans = engine.compile_patterns(pats)
+    plans = engine.compile_patterns(pats, k=k)
     idx = engine.build_index(t[None], np.array([length], np.int32))
     whole_c = np.asarray(engine.count_many(idx, plans, end_min=end_min))
     whole_m = np.asarray(engine.match_many(idx, plans, end_min=end_min))
@@ -348,6 +352,8 @@ def test_windowed_scan_equals_whole_text(
     np.testing.assert_array_equal(
         np.asarray(engine.match_many(idx, plans, end_min=end_min)), whole_m
     )
+    # counts and masks agree with each other, not only with themselves
+    np.testing.assert_array_equal(whole_m.sum(axis=2), whole_c)
 
 
 def test_text_index_derives_text_from_packed(rng):

@@ -11,6 +11,8 @@ from repro.analysis.hlo_collectives import collective_stats
 from repro.analysis.jaxpr_cost import step_cost
 from repro.analysis.roofline import collective_bytes, roofline_terms
 
+from conftest import REPO_ROOT, subprocess_env
+
 
 def test_walker_matches_unrolled_hlo():
     def f(x, w):
@@ -135,6 +137,6 @@ print("COLL_OK", n)
     res = subprocess.run(
         [sys.executable, "-c", script],
         capture_output=True, text=True, timeout=600,
-        env={**os.environ, "PYTHONPATH": "src"}, cwd="/root/repo",
+        env=subprocess_env(), cwd=REPO_ROOT,
     )
     assert "COLL_OK" in res.stdout, res.stdout + res.stderr

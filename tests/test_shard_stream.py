@@ -353,7 +353,7 @@ def test_distributed_scan_inprocess_mesh(rng):
         assert int(c(jax.numpy.asarray(t), jax.numpy.asarray(p))) == oracle.sum()
 
 
-def test_compat_make_mesh_fallback_branch():
+def test_sum_across_devices_device_subsets():
     """sum_across_devices builds its mesh over only the devices that hold
     parts: parts on a subset of the devices (here two of each device in
     use, and one device alone) merge to the host sum."""
