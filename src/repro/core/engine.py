@@ -109,6 +109,26 @@ CAND_BLOCK = 32
 # EPSMC_BETA, so every window keeps the global block phase.
 SEGMENT_BYTES = 1 << 20
 
+
+def _stage(name: str):
+    """Trace the decorated function under ``jax.named_scope(name)``: its
+    device operations then carry the engine stage (``index``, ``filter``,
+    ``compact``, ``verify``, ``dense``, ``automaton``, ``segment``) in the
+    ``op_name`` metadata of the compiled HLO.  One scope per stage, on the
+    function that does the stage's work.  A fresh scope per call: one
+    shared scope object is not safe across tracing threads."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return scoped
+
+    return wrap
+
+
 # Fingerprint constants live in packing.py next to the mixing primitives;
 # the private aliases keep existing importers (approx.relaxed, the Pallas
 # kernels) working unchanged.
@@ -195,8 +215,9 @@ def build_index(
     if lengths is None:
         lengths = jnp.full((B,), n, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    packed = pack_u32(t)
-    block_fp = hash_text_blocks(t, fingerprint_weights(beta), kbits)
+    with jax.named_scope("index"):
+        packed = pack_u32(t)
+        block_fp = hash_text_blocks(t, fingerprint_weights(beta), kbits)
     return TextIndex(packed=packed, block_fp=block_fp, lengths=lengths)
 
 
@@ -283,6 +304,7 @@ class FingerprintBank:
             self._prefix[i + 1] = acc
         return self._prefix[nterms]
 
+    @_stage("filter")
     def window_fp(self, m: int, kbits: int) -> jnp.ndarray:
         """(B, n) int32 fingerprint of the m-byte window at every position —
         bit-identical to _window_fingerprint(packed, _word_offsets(m), kbits)."""
@@ -779,6 +801,7 @@ def _valid_starts(
     return ok
 
 
+@_stage("dense")
 def _match_group_a(
     index: TextIndex,
     plan: PatternPlan,
@@ -794,6 +817,7 @@ def _match_group_a(
     return acc
 
 
+@_stage("dense")
 def _dense_b(index: TextIndex, plan: PatternPlan, end_min=None) -> jnp.ndarray:
     """Stacked-anchor dense compare: AND over packed word compares.  This is
     the exact EPSMb filter+verify fused — also the overflow fallback."""
@@ -830,6 +854,7 @@ def _expected_union_blocks(
     return int(B * nblk * rho), rho
 
 
+@_stage("filter")
 def _b_candidates(
     index: TextIndex,
     plan: PatternPlan,
@@ -857,6 +882,7 @@ def _b_candidates(
     return blk_any, budget, nblk
 
 
+@_stage("compact")
 def _gather_candidate_rows(
     index: TextIndex, m: int, blk_any, budget, nblk, cblock: int = CAND_BLOCK
 ):
@@ -889,6 +915,7 @@ def _start_gate(index: TextIndex, m: int, starts, bvec, end_min):
     return ok
 
 
+@_stage("verify")
 def _b_verify(
     index: TextIndex, plan: PatternPlan, blk_any, budget, nblk, end_min=None
 ):
@@ -913,6 +940,7 @@ def _b_verify(
     return ok, bvec, starts
 
 
+@_stage("dense")
 def _dense_count(
     index: TextIndex, plan: PatternPlan, dense_fn, end_min=None
 ) -> jnp.ndarray:
@@ -936,6 +964,7 @@ def _match_group_b(
     return _dense_b(index, plan, end_min)
 
 
+@_stage("verify")
 def _b_verify_pid(
     index: TextIndex, plan: PatternPlan, blk_any, budget, nblk, end_min=None
 ):
@@ -961,6 +990,7 @@ def _b_verify_pid(
     return ok.astype(jnp.int32), bvec, pid
 
 
+@_stage("automaton")
 def _automaton_counts(index: TextIndex, auto, end_min=None) -> jnp.ndarray:
     """(B, N_input) exact counts via the packed Aho-Corasick fallback —
     linear in n regardless of candidate density (DESIGN.md §14)."""
@@ -969,6 +999,7 @@ def _automaton_counts(index: TextIndex, auto, end_min=None) -> jnp.ndarray:
     return count_automaton(index.text, index.lengths, auto, end_min=end_min)
 
 
+@_stage("verify")
 def _b_count_rows_csr(
     index: TextIndex,
     plan: PatternPlan,
@@ -1010,6 +1041,7 @@ def _b_count_rows_csr(
     return counts
 
 
+@_stage("dense")
 def _count_b_slot_dense(
     index: TextIndex,
     plan: PatternPlan,
@@ -1248,12 +1280,13 @@ def _count_groups_b_shared(
     union = None
     for p in plans:
         h = bank.window_fp(p.m, p.kbits)
-        cand = p.lut_any[h] & _valid_starts(index, p.m, end_min)
-        blk = (
-            jnp.pad(cand, ((0, 0), (0, nblk * C - n)))
-            .reshape(B, nblk, C)
-            .any(-1)
-        )
+        with jax.named_scope("filter"):
+            cand = p.lut_any[h] & _valid_starts(index, p.m, end_min)
+            blk = (
+                jnp.pad(cand, ((0, 0), (0, nblk * C - n)))
+                .reshape(B, nblk, C)
+                .any(-1)
+            )
         union = blk if union is None else union | blk
 
     def sparse(_):
@@ -1319,6 +1352,7 @@ def _dense_c(index: TextIndex, plan: PatternPlan, end_min=None) -> jnp.ndarray:
     return _match_group_a(index, plan, None, end_min)
 
 
+@_stage("filter")
 def _c_candidates(index: TextIndex, plan: PatternPlan):
     """Probe the union LUT at the strided inspected blocks (paper Fig. 1
     bottom, many patterns at once).  Every occurrence has exactly ONE
@@ -1348,21 +1382,24 @@ def _c_candidates(index: TextIndex, plan: PatternPlan):
     return ht, cand, stride, noff_used, budget
 
 
+@_stage("verify")
 def _c_verify(index, plan, ht, cand, stride, noff_used, budget, end_min=None):
     """Verify candidate blocks against all P patterns at the <= stride
     offsets, gated by the LUT's pattern-id payload bitmask."""
     B, n = index.packed.shape
     m = plan.m
     G = cand.shape[1]
-    (flat,) = jnp.nonzero(cand.reshape(-1), size=budget, fill_value=B * G)
-    live = flat < B * G
-    flat = jnp.where(live, flat, 0)
-    bvec = flat // G
-    bsel = (flat % G) * stride  # inspected block start
     front = noff_used - 1
     span = front + m
-    t_pad = jnp.pad(index.text, ((0, 0), (front, span)))
-    rows = t_pad[bvec[:, None], bsel[:, None] + jnp.arange(span)]  # (nb, span)
+    with jax.named_scope("compact"):
+        (flat,) = jnp.nonzero(cand.reshape(-1), size=budget, fill_value=B * G)
+        live = flat < B * G
+        flat = jnp.where(live, flat, 0)
+        bvec = flat // G
+        bsel = (flat % G) * stride  # inspected block start
+        t_pad = jnp.pad(index.text, ((0, 0), (front, span)))
+        # (nb, span)
+        rows = t_pad[bvec[:, None], bsel[:, None] + jnp.arange(span)]
     # pattern-id payload: which patterns registered this fingerprint?
     P = plan.n_patterns
     bits = plan.lut_bits[ht.reshape(-1)[jnp.where(live, flat, 0)]]  # (nb, W)
@@ -1389,6 +1426,7 @@ def _c_verify(index, plan, ht, cand, stride, noff_used, budget, end_min=None):
     return ok_all, b_all, st_all
 
 
+@_stage("verify")
 def _c_verify_csr(
     index, plan, ht, cand, stride, noff_used, budget, end_min=None
 ):
@@ -1411,15 +1449,17 @@ def _c_verify_csr(
     B, n = index.packed.shape
     m = plan.m
     G = cand.shape[1]
-    (flat,) = jnp.nonzero(cand.reshape(-1), size=budget, fill_value=B * G)
-    live = flat < B * G
-    flat = jnp.where(live, flat, 0)
-    bvec = flat // G
-    bsel = (flat % G) * stride  # inspected block start
     front = noff_used - 1
     span = front + m
-    t_pad = jnp.pad(index.text, ((0, 0), (front, span)))
-    rows = t_pad[bvec[:, None], bsel[:, None] + jnp.arange(span)]  # (nb, span)
+    with jax.named_scope("compact"):
+        (flat,) = jnp.nonzero(cand.reshape(-1), size=budget, fill_value=B * G)
+        live = flat < B * G
+        flat = jnp.where(live, flat, 0)
+        bvec = flat // G
+        bsel = (flat % G) * stride  # inspected block start
+        t_pad = jnp.pad(index.text, ((0, 0), (front, span)))
+        # (nb, span)
+        rows = t_pad[bvec[:, None], bsel[:, None] + jnp.arange(span)]
     h = ht.reshape(-1)[flat]
     base = plan.c_slot_off[h]
     cnt = plan.c_slot_off[h + 1] - base
@@ -1683,6 +1723,7 @@ def _segment_overlap(plans: Sequence[PatternPlan]) -> int:
     return -(-(max(p.m for p in plans) - 1) // EPSMC_BETA) * EPSMC_BETA
 
 
+@_stage("segment")
 def _window_index(index: TextIndex, st, width: int, cap) -> TextIndex:
     """Columns [st, st + width) of a resident index (``st`` traced, a
     multiple of EPSMC_BETA), each row's length cut at ``cap`` columns."""

@@ -69,7 +69,7 @@ from repro.obs.recorder import Recorder, logging_sink
 _LOG = logging.getLogger("repro.stream")
 
 # The module's default flight recorder: disabled (no spans, no fencing, no
-# buffers — the <2% bench_obs budget) but with the module logger as an event
+# buffers, no profiler annotations) but with the module logger as an event
 # sink, so the pre-recorder log lines (auto-chunk probe, kernel fallback,
 # stragglers) keep appearing when no recorder is attached (DESIGN.md §13).
 _DEFAULT_REC = Recorder(enabled=False, fence=False, sinks=(logging_sink(_LOG),))
@@ -302,12 +302,15 @@ def _fused_count_step(
     occurrences whose END falls in the newly-streamed region, replacing the
     reference path's separate overlap-prefix subtraction (DESIGN.md §11
     proves the two produce identical integers).  One count_many — i.e. one
-    fingerprint-bank pass and one shared compaction — per chunk."""
+    fingerprint-bank pass and one shared compaction — per chunk.  Its
+    device operations carry their stage in ``op_name``: ``index``, then
+    ``count`` with the engine's own stages inside, then ``accumulate``."""
     del ov  # the fused gate needs no prefix sub-index
     idx = engine.build_index(window[None, :], jnp.asarray(length)[None])
-    return counts + engine.count_many(
-        idx, plans, k=k, end_min=prev_ov, shared=shared
-    )[0]
+    with jax.named_scope("count"):
+        c = engine.count_many(idx, plans, k=k, end_min=prev_ov, shared=shared)
+    with jax.named_scope("accumulate"):
+        return counts + c[0]
 
 
 def _count_step(
@@ -327,13 +330,17 @@ def _count_step(
     its cost is noise next to the O(N) window scan, and both run in this one
     dispatch."""
     idx = engine.build_index(window[None, :], jnp.asarray(length)[None])
-    c = engine.count_many(idx, plans, k=k, shared=shared)
+    with jax.named_scope("count"):
+        c = engine.count_many(idx, plans, k=k, shared=shared)
     if ov:
-        pre_idx = engine.build_index(
-            window[None, :ov], jnp.minimum(jnp.asarray(prev_ov), length)[None]
-        )
-        c = c - engine.count_many(pre_idx, plans, k=k, shared=shared)
-    return counts + c[0]
+        with jax.named_scope("seam_gate"):
+            pre_idx = engine.build_index(
+                window[None, :ov],
+                jnp.minimum(jnp.asarray(prev_ov), length)[None],
+            )
+            c = c - engine.count_many(pre_idx, plans, k=k, shared=shared)
+    with jax.named_scope("accumulate"):
+        return counts + c[0]
 
 
 @functools.lru_cache(maxsize=None)
@@ -360,19 +367,24 @@ def _mask_step(window, length, prev_ov, plans, *, k, fused: bool = True):
     seam: a start survives iff its occurrence ENDS at or past ``prev_ov``
     (ends inside the carried prefix belong to the previous chunk).  The
     fused form pushes that gate into the matchers' candidate masks
-    (``end_min``); the reference form post-filters — bit-identical."""
+    (``end_min``); the reference form post-filters — bit-identical.  Its
+    device operations carry ``index``, then ``match`` with the engine's own
+    stages inside (and the reference form's ``seam_gate``) in ``op_name``."""
     idx = engine.build_index(window[None, :], jnp.asarray(length)[None])
     if fused:
-        return engine.match_many(idx, plans, k=k, end_min=prev_ov)[0]
-    mask = engine.match_many(idx, plans, k=k)[0]
-    pos = jnp.arange(window.shape[0], dtype=jnp.int32)
-    keeps = []
-    for plan in plans:
-        keep = pos + (plan.m - 1) >= prev_ov
-        keeps.append(
-            jnp.broadcast_to(keep[None, :], (plan.n_patterns, window.shape[0]))
-        )
-    return mask & jnp.concatenate(keeps, axis=0)
+        with jax.named_scope("match"):
+            return engine.match_many(idx, plans, k=k, end_min=prev_ov)[0]
+    with jax.named_scope("match"):
+        mask = engine.match_many(idx, plans, k=k)[0]
+    with jax.named_scope("seam_gate"):
+        pos = jnp.arange(window.shape[0], dtype=jnp.int32)
+        keeps = []
+        for plan in plans:
+            keep = pos + (plan.m - 1) >= prev_ov
+            keeps.append(jnp.broadcast_to(
+                keep[None, :], (plan.n_patterns, window.shape[0])
+            ))
+        return mask & jnp.concatenate(keeps, axis=0)
 
 
 class StreamScanner:
@@ -417,12 +429,18 @@ class StreamScanner:
     ``recorder`` attaches a :class:`~repro.obs.recorder.Recorder` (DESIGN.md
     §13): every chunk then traces a ``host_prep`` span (source read /
     decompress / window assembly), a ``device_put`` span, and a fenced
-    ``dispatch`` span (the jitted scan, seam fusion included), plus
-    ``dispatches``/``bytes_scanned`` counters.  The default is the module's
-    disabled recorder — no spans, no fencing, the double-buffered pipeline
-    untouched — whose only effect is feeding instant events (auto-chunk
-    probe, kernel fallback, stragglers) to the module logger.  ``lane``
-    names this scanner's trace track (the sharded scanner sets it).
+    ``dispatch`` span (the jitted scan, seam fusion included), plus the
+    ``bytes_scanned`` (new stream bytes) and ``put_bytes`` (window bytes
+    sent to the device) counters; the mask path adds ``wait`` (the host's
+    block on the mask step), ``fetch`` (the mask's copy to the host, its
+    bytes counted in ``fetch_bytes``) and ``positions`` (the mask's rows to
+    positions) spans, and ``count_many`` one ``fetch`` of its counts.
+    Every span of one chunk carries the same ``chunk`` id.  The default is
+    the module's disabled recorder — no spans, no fencing, the
+    double-buffered pipeline untouched — whose only effect is feeding
+    instant events (auto-chunk probe, kernel fallback, stragglers) to the
+    module logger.  ``lane`` names this scanner's trace track (the sharded
+    scanner sets it).
 
     ``watchdog`` arms a :class:`~repro.dist.fault_tolerance.StepWatchdog`
     around every chunk's HOST step — source read, decompression, window
@@ -497,6 +515,7 @@ class StreamScanner:
         self.n_patterns = sum(p.n_patterns for p in self.plans)
         self.order = engine.plan_order(self.plans)
         self.dispatch_count = 0
+        self._chunks = 0  # windows produced: the next window's chunk id
         self.watchdog = watchdog
         self.on_straggler = on_straggler
 
@@ -527,13 +546,16 @@ class StreamScanner:
 
     def _windows(
         self, source, *, prefix=None, start: int = 0
-    ) -> Iterator[Tuple[np.ndarray, int, int, int]]:
-        """Yield (window (N,) uint8, valid_len, carry_len, base): fixed-
-        capacity host windows where window[:carry_len] re-feeds the previous
-        window's tail and ``base`` is the global position of window[0].
-        ``prefix``/``start`` seed the first window's carry for mid-stream
-        ranges (the first chunk's seam subtraction then removes occurrences
-        the preceding range already owned)."""
+    ) -> Iterator[Tuple[np.ndarray, int, int, int, bool]]:
+        """Yield (window (N,) uint8, valid_len, carry_len, base, last):
+        fixed-capacity host windows where window[:carry_len] re-feeds the
+        previous window's tail and ``base`` is the global position of
+        window[0].  ``last`` says the source has ended and this window took
+        its final bytes (a source that ends exactly on a window's edge is
+        found out only by the next read).  ``prefix``/``start`` seed the
+        first window's carry for mid-stream ranges (the first chunk's seam
+        subtraction then removes occurrences the preceding range already
+        owned)."""
         N, ov = self.window_bytes, self.overlap
         pieces: deque = deque()
         have = 0
@@ -567,7 +589,7 @@ class StreamScanner:
                 need -= take
             have -= new_len
             L = len(carry) + new_len
-            yield win, L, len(carry), base
+            yield win, L, len(carry), base, exhausted and not have
             carry = win[max(0, L - ov) : L].copy() if ov else carry
             base += L - len(carry)
 
@@ -577,17 +599,24 @@ class StreamScanner:
         and, when a watchdog is armed, timed for straggling: the stall site
         for slow storage.  A flagged chunk either raises (policy="raise") or
         is recorded as a ``straggler`` event and reported to
-        ``on_straggler``."""
+        ``on_straggler``.
+
+        Yields (chunk, window, valid_len, carry_len, base).  ``chunk`` is
+        the window's id, counted over the scanner's life: every span of
+        the window's work (``host_prep``, ``device_put``, ``dispatch``,
+        ``wait``, ``fetch``, ``positions``) carries it."""
         rec, lane = self.rec, self.lane
         wd = self.watchdog
         it = self._windows(source, prefix=prefix, start=start)
         step = 0
-        while True:
+        last = False
+        while not last:
+            chunk = self._chunks
             if wd is not None:
                 wd.start_step(step)
             try:
-                with rec.span("host_prep", lane=lane, step=step) as sp:
-                    win, L, carry_len, base = next(it)
+                with rec.span("host_prep", lane=lane, chunk=chunk) as sp:
+                    win, L, carry_len, base, last = next(it)
                     sp.set(bytes=int(L) - int(carry_len))
             except StopIteration:
                 if wd is not None:
@@ -604,25 +633,27 @@ class StreamScanner:
                 if self.on_straggler is not None:
                     self.on_straggler(ev)
             step += 1
-            yield win, L, carry_len, base
+            self._chunks += 1
+            yield chunk, win, L, carry_len, base
 
     # -- device loop --------------------------------------------------------
 
-    def _put(self, win):
-        """Host->device window transfer under a ``device_put`` span.  The
-        transfer itself is async; the fence (enabled recorder only) charges
-        the copy to this span instead of the next dispatch."""
+    def _put(self, chunk, win):
+        """Host->device window transfer under a ``device_put`` span, its
+        bytes counted in ``put_bytes``.  The transfer itself is async; the
+        fence (enabled recorder only) charges the copy to this span instead
+        of the next dispatch."""
+        self.rec.count("put_bytes", int(win.nbytes))
         with self.rec.span(
-            "device_put", lane=self.lane, bytes=int(win.nbytes)
+            "device_put", lane=self.lane, chunk=chunk, bytes=int(win.nbytes)
         ) as sp:
             return sp.fence(jax.device_put(win, self.device))
 
-    def _dispatch_count(self, counts, window_dev, length, prev_ov):
+    def _dispatch_count(self, counts, chunk, window_dev, length, prev_ov):
         self.dispatch_count += 1
         new_bytes = int(length) - int(prev_ov)
         with self.rec.span(
-            "dispatch", lane=self.lane, chunk=self.dispatch_count,
-            bytes=new_bytes,
+            "dispatch", lane=self.lane, chunk=chunk, bytes=new_bytes,
         ) as sp:
             if self.spec is not None:
                 counts = _jitted_kernel_step(self.spec)(
@@ -636,7 +667,6 @@ class StreamScanner:
             # seam fusion (end_min gate / overlap sub-index) runs inside this
             # same dispatch; the fence makes the span cover the device work
             sp.fence(counts)
-        self.rec.count("dispatches")
         self.rec.count("bytes_scanned", new_bytes)
         return counts
 
@@ -654,13 +684,13 @@ class StreamScanner:
         is consumed, and nothing here waits on device results at all."""
         counts = self._zero_counts()
         pending = None
-        for win, L, carry_len, _base in self._steps(
+        for chunk, win, L, carry_len, _base in self._steps(
             source, prefix=prefix, start=start
         ):
-            dev = self._put(win)
+            dev = self._put(chunk, win)
             if pending is not None:
                 counts = self._dispatch_count(counts, *pending)
-            pending = (dev, np.int32(L), np.int32(carry_len))
+            pending = (chunk, dev, np.int32(L), np.int32(carry_len))
         if pending is not None:
             counts = self._dispatch_count(counts, *pending)
         return counts
@@ -669,9 +699,8 @@ class StreamScanner:
         """int32 (P_total,) exact occurrence counts over the whole stream
         (or, with ``prefix``/``start``, over one mid-stream range — counting
         exactly the occurrences whose END lies inside it)."""
-        return np.asarray(
-            jax.device_get(self.count_device(source, prefix=prefix, start=start))
-        )
+        counts = self.count_device(source, prefix=prefix, start=start)
+        return self._fetch(counts)
 
     def any_many(self, source) -> np.ndarray:
         """bool (P_total,) — does each pattern occur anywhere in the stream?"""
@@ -684,14 +713,14 @@ class StreamScanner:
         counts = self._zero_counts()
         pending = None
         chunks = 0
-        for win, L, carry_len, _base in self._steps(source):
-            dev = self._put(win)
+        for chunk, win, L, carry_len, _base in self._steps(source):
+            dev = self._put(chunk, win)
             if pending is not None:
                 counts = self._dispatch_count(counts, *pending)
                 chunks += 1
                 if chunks % sync_every == 0 and bool(counts.sum() > 0):
                     return True
-            pending = (dev, np.int32(L), np.int32(carry_len))
+            pending = (chunk, dev, np.int32(L), np.int32(carry_len))
         if pending is not None:
             counts = self._dispatch_count(counts, *pending)
         return bool(np.asarray(jax.device_get(counts)).sum() > 0)
@@ -706,42 +735,63 @@ class StreamScanner:
         length (starts before new_start - max_m + 1 are always False).
         With ``prefix``/``start``, bases are global stream positions and
         occurrences ending before ``start`` are dropped (previous range's)."""
+        for _chunk, out in self._masks(source, prefix=prefix, start=start):
+            yield out
+
+    def _masks(self, source, *, prefix, start: int):
+        """``masks``, each mask paired with its chunk id."""
         pending = None
-        for win, L, carry_len, base in self._steps(
+        for chunk, win, L, carry_len, base in self._steps(
             source, prefix=prefix, start=start
         ):
-            dev = self._put(win)
+            dev = self._put(chunk, win)
             if pending is not None:
-                yield self._flush_mask(*pending)
-            pending = (dev, np.int32(L), np.int32(carry_len), base, L)
+                yield pending[0], self._flush_mask(*pending)
+            pending = (chunk, dev, np.int32(L), np.int32(carry_len), base, L)
         if pending is not None:
-            yield self._flush_mask(*pending)
+            yield pending[0], self._flush_mask(*pending)
 
-    def _flush_mask(self, dev, length, prev_ov, base, L):
+    def _flush_mask(self, chunk, dev, length, prev_ov, base, L):
+        """Dispatch one chunk's mask step and bring its mask to the host.
+        Traced, the host's block on the step is the ``wait`` span and the
+        copy alone the ``fetch`` span; untraced, the copy syncs by itself."""
         self.dispatch_count += 1
         new_bytes = int(length) - int(prev_ov)
-        with self.rec.span(
-            "dispatch", lane=self.lane, chunk=self.dispatch_count,
-            bytes=new_bytes,
+        rec, lane = self.rec, self.lane
+        with rec.span(
+            "dispatch", lane=lane, chunk=chunk, bytes=new_bytes,
         ) as sp:
             mask = sp.fence(_mask_step(
                 dev, length, prev_ov, self.plans, k=self.k, fused=self.fused
             ))
-        self.rec.count("dispatches")
-        self.rec.count("bytes_scanned", new_bytes)
-        return base, int(prev_ov), np.asarray(jax.device_get(mask))[:, :L]
+        rec.count("bytes_scanned", new_bytes)
+        with rec.span("wait", lane=lane, chunk=chunk) as sp:
+            sp.sync(mask)
+        return base, int(prev_ov), self._fetch(mask, chunk)[:, :L]
+
+    def _fetch(self, value, chunk=None):
+        """Device->host copy of ``value`` under a ``fetch`` span, its bytes
+        counted in ``fetch_bytes``."""
+        with self.rec.span("fetch", lane=self.lane, chunk=chunk):
+            out = np.asarray(jax.device_get(value))
+        self.rec.count("fetch_bytes", out.nbytes)
+        return out
 
     def positions_many(
         self, source, *, prefix=None, start: int = 0
     ) -> List[np.ndarray]:
         """Per-pattern sorted global occurrence start positions (host side;
-        output-sized host memory, still O(chunk) device memory)."""
+        output-sized host memory, still O(chunk) device memory).  Each
+        chunk's extraction from its mask is a ``positions`` span."""
         out: List[List[np.ndarray]] = [[] for _ in range(self.n_patterns)]
-        for base, _new_start, mask in self.masks(source, prefix=prefix, start=start):
-            for p_i in range(self.n_patterns):
-                (loc,) = np.nonzero(mask[p_i])
-                if len(loc):
-                    out[p_i].append(loc.astype(np.int64) + base)
+        for chunk, (base, _new_start, mask) in self._masks(
+            source, prefix=prefix, start=start
+        ):
+            with self.rec.span("positions", lane=self.lane, chunk=chunk):
+                for p_i in range(self.n_patterns):
+                    (loc,) = np.nonzero(mask[p_i])
+                    if len(loc):
+                        out[p_i].append(loc.astype(np.int64) + base)
         return [
             np.concatenate(o) if o else np.zeros(0, np.int64) for o in out
         ]

@@ -11,7 +11,12 @@ substrate the :class:`~repro.obs.recorder.Recorder` builds on:
     managers; nesting needs no parent bookkeeping because the Chrome
     viewer nests complete events on one track by ``ts``/``dur``
     containment, which holds by construction (a child enters after and
-    exits before its parent on the same lane).
+    exits before its parent on the same lane).  While open, a span also
+    holds a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so
+    under ``jax.profiler`` the program's spans land in the profile's host
+    plane, on the same clock as the device's operations.  The annotation
+    takes no arguments: the profiler would encode them into the event's
+    name.
 
   * **fencing** — JAX dispatch is asynchronous: a jitted call returns as
     soon as the work is enqueued, so a naive ``with span(): f(x)`` times
@@ -37,8 +42,9 @@ substrate the :class:`~repro.obs.recorder.Recorder` builds on:
     buffer's origin.  ``benchmarks/validate_trace.py`` is the stdlib-only
     schema gate CI runs over these exports.
 
-Zero dependencies: ``jax`` is imported only inside ``fence`` on fenced
-spans, so the module (and every no-op path) stays stdlib-only.
+Zero dependencies on the no-op path: ``jax`` is imported only by enabled
+spans (the profiler annotation, ``fence`` and ``sync``), so the module and
+:data:`NULL_SPAN` stay stdlib-only.
 """
 
 from __future__ import annotations
@@ -59,9 +65,9 @@ _PH_INSTANT = "i"
 
 class NullSpan:
     """The reusable do-nothing span a disabled recorder hands out: enter,
-    exit, ``set``, and ``fence`` all no-op (``fence`` returns its argument
-    WITHOUT syncing — the disabled path must never change the engine's
-    async dispatch shape)."""
+    exit, ``set``, ``fence`` and ``sync`` all no-op (``fence`` and ``sync``
+    return their argument WITHOUT syncing — the disabled path must never
+    change the engine's async dispatch shape)."""
 
     __slots__ = ()
 
@@ -77,6 +83,9 @@ class NullSpan:
     def fence(self, value):
         return value
 
+    def sync(self, value):
+        return value
+
 
 NULL_SPAN = NullSpan()
 
@@ -88,19 +97,25 @@ class Span:
     known only after the window is assembled).  ``fence(value)`` blocks
     until ``value``'s device work is done — still inside the span — when
     the owning recorder fences, and is a pass-through otherwise.
+    ``sync(value)`` blocks whatever the recorder's fencing: it is for a
+    span that times a wait the untraced path pays later anyway.
     """
 
-    __slots__ = ("name", "args", "t0", "_buf", "_metrics", "_fenced")
+    __slots__ = ("name", "args", "t0", "_buf", "_fenced", "_annotation")
 
-    def __init__(self, name: str, args: dict, buf: list, metrics, fenced: bool):
+    def __init__(self, name: str, args: dict, buf: list, fenced: bool):
         self.name = name
         self.args = args
         self._buf = buf
-        self._metrics = metrics
         self._fenced = fenced
         self.t0 = 0.0
+        self._annotation = None
 
     def __enter__(self) -> "Span":
+        import jax  # lazy: the no-op paths never touch jax
+
+        self._annotation = jax.profiler.TraceAnnotation("repro." + self.name)
+        self._annotation.__enter__()
         self.t0 = _now()
         return self
 
@@ -109,17 +124,21 @@ class Span:
         return self
 
     def fence(self, value):
-        if self._fenced and value is not None:
-            import jax  # lazy: the no-op paths never touch jax
+        if self._fenced:
+            self.sync(value)
+        return value
+
+    def sync(self, value):
+        if value is not None:
+            import jax
 
             jax.block_until_ready(value)
         return value
 
     def __exit__(self, *exc) -> bool:
         dur = _now() - self.t0
+        self._annotation.__exit__(*exc)
         self._buf.append((_PH_SPAN, self.name, self.t0, dur, self.args))
-        if self._metrics is not None:
-            self._metrics.observe("span/" + self.name, dur)
         return False
 
 

@@ -273,6 +273,126 @@ def test_stop_scanner_records_fenced_spans():
     assert rec.metrics.summary()["counters"]["stop_scan_dispatches"] == 4
 
 
+# -- the profiler's clock: spans as host events in the .xplane.pb ----------
+
+CHUNK_SPANS = ("host_prep", "device_put", "dispatch", "wait", "fetch",
+               "positions")
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under ``jax.profiler.trace``; return its result and the
+    names of the host events named ``repro.*``, as ProfileData reads them."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(tmp_path)):
+        out = fn()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    names = [
+        ev.name
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("repro.")
+    ]
+    return out, names
+
+
+def _chunk_ids(rec, name):
+    return [args.get("chunk") for rows in rec.trace.snapshot().values()
+            for _ph, n, _ts, _dur, args in rows if n == name]
+
+
+def test_locate_spans_land_on_the_profiler_clock(rng, tmp_path):
+    """Every per-chunk span of a traced locate scan is one host event named
+    exactly ``repro.<span>`` per chunk, and all carry one chunk id each."""
+    text, plans = _corpus(rng, n=50_000)
+    rec = Recorder(enabled=True, fence=False)
+    sc = StreamScanner(plans, 8192, recorder=rec)
+    _, names = _profiled(tmp_path, lambda: sc.positions_many(text))
+    n = sc.dispatch_count
+    assert n == -(-(len(text) - sc.overlap) // sc.step_bytes) > 4
+    assert sorted(set(names)) == sorted(f"repro.{s}" for s in CHUNK_SPANS)
+    for span in CHUNK_SPANS:
+        assert names.count(f"repro.{span}") == n, span
+        assert _chunk_ids(rec, span) == list(range(n)), span
+
+
+def test_count_spans_share_chunk_ids_and_fetch_once(rng, tmp_path):
+    text, plans = _corpus(rng, n=50_000)
+    rec = Recorder(enabled=True, fence=False)
+    sc = StreamScanner(plans, 8192, recorder=rec)
+    got, names = _profiled(tmp_path, lambda: sc.count_many(text))
+    want = StreamScanner(plans, 8192).count_many(text)
+    np.testing.assert_array_equal(got, want)
+    n = sc.dispatch_count
+    for span in ("host_prep", "device_put", "dispatch"):
+        assert names.count(f"repro.{span}") == n
+        assert _chunk_ids(rec, span) == list(range(n))
+    assert names.count("repro.fetch") == 1  # the accumulator, once per scan
+    assert "repro.wait" not in names and "repro.positions" not in names
+    counters = rec.metrics.summary()["counters"]
+    assert counters["fetch_bytes"] == got.nbytes
+    assert counters["bytes_scanned"] == len(text)
+    assert counters["put_bytes"] == n * sc.window_bytes
+
+
+def test_disabled_recorder_makes_no_profiler_annotation(rng, tmp_path,
+                                                        monkeypatch):
+    import jax
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("a disabled recorder annotated the profile")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    text, plans = _corpus(rng, n=30_000)
+    sc = StreamScanner(plans, 8192, recorder=Recorder(enabled=False))
+    _, names = _profiled(
+        tmp_path, lambda: (sc.positions_many(text), sc.count_many(text))
+    )
+    assert names == []
+    with pytest.raises(AssertionError):
+        with Recorder(enabled=True).span("host_prep"):
+            pass
+
+
+def test_fetch_bytes_count_every_mask_copied(rng):
+    text, plans = _corpus(rng, n=50_000)
+    rec = Recorder(enabled=True, fence=False)
+    sc = StreamScanner(plans, 8192, recorder=rec)
+    sc.positions_many(text)
+    counters = rec.metrics.summary()["counters"]
+    assert counters["fetch_bytes"] == (
+        sc.dispatch_count * sc.n_patterns * sc.window_bytes
+    )
+    assert counters["put_bytes"] == sc.dispatch_count * sc.window_bytes
+    assert counters["bytes_scanned"] == len(text)
+    assert "dispatches" not in counters
+
+
+@pytest.mark.parametrize("fence", [False, True])
+def test_enabled_recorder_leaves_answers_unchanged(rng, fence):
+    text, plans = _corpus(rng, n=50_000)
+    want_pos = StreamScanner(plans, 8192).positions_many(text)
+    want_counts = StreamScanner(plans, 8192).count_many(text)
+    rec = Recorder(enabled=True, fence=fence)
+    got_pos = StreamScanner(plans, 8192, recorder=rec).positions_many(text)
+    got_counts = StreamScanner(plans, 8192, recorder=rec).count_many(text)
+    np.testing.assert_array_equal(got_counts, want_counts)
+    assert len(got_pos) == len(want_pos)
+    for g, w in zip(got_pos, want_pos):
+        np.testing.assert_array_equal(g, w)
+
+
+def test_span_writes_no_histogram():
+    rec = Recorder(enabled=True, fence=False)
+    with rec.span("dispatch", chunk=0):
+        pass
+    assert rec.metrics.summary()["histograms"] == {}
+    assert rec.summary()["spans"]["dispatch"]["count"] == 1
+
+
 # -- metrics + export plumbing ---------------------------------------------
 
 

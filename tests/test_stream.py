@@ -554,3 +554,49 @@ def test_stream_watchdog_flags_stalled_chunk(rng):
     # no watchdog, no timing: the plain path is untouched
     got_plain = StreamScanner(plans, 1024).count_many(stalling_chunks(0.0))
     np.testing.assert_array_equal(got_plain, want)
+
+
+# Literals of every regime (EPSMa m < 4, EPSMb, EPSMc m >= 16), as a log
+# scan job asks for them.
+SCOPE_QUERIES = (b"Got", b"blk_", b"timeout!", b"PacketResponder",
+                 b"Verification succeeded", b"too many open files (24)",
+                 b"BLOCK* NameSystem.addStoredBlock")
+ENGINE_STAGES = {"index", "filter", "compact", "verify", "dense"}
+
+
+def _op_name_stages(hlo_text):
+    import re
+
+    return {part for op in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for part in op.split("/")}
+
+
+@pytest.mark.parametrize("step,fused", [
+    ("count", True), ("mask", True), ("count", False), ("mask", False),
+])
+def test_steps_name_their_stages_in_op_name(step, fused):
+    """The compiled chunk steps carry the engine's stages in their ops'
+    ``op_name`` metadata (what a trace's ops are mapped to stages by), the
+    reference forms their own seam stage; and the count step's module is
+    still ``jit__unknown``, the name its roofline metric reads."""
+    from repro.core import stream
+
+    plans = engine.compile_patterns_cached(list(SCOPE_QUERIES), k=0)
+    sc = StreamScanner(plans, 1 << 16, use_kernel=False, fused=fused)
+    N = sc.window_bytes
+    win = jnp.zeros((N,), jnp.uint8)
+    args = (win, np.int32(N), np.int32(sc.overlap), sc.plans)
+    if step == "count":
+        lowered = stream._jitted_count_step(fused, sc.shared).lower(
+            sc._zero_counts(), *args, ov=sc.overlap, k=0
+        )
+        want = ENGINE_STAGES | {"count", "accumulate"}
+    else:
+        lowered = stream._mask_step.lower(*args, k=0, fused=fused)
+        want = ENGINE_STAGES | {"match"}
+    if not fused:
+        want |= {"seam_gate"}
+    text = lowered.compile().as_text()
+    assert want <= _op_name_stages(text)
+    if step == "count":
+        assert text.startswith("HloModule jit__unknown,")
