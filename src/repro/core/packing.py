@@ -9,13 +9,18 @@ tests a 4-gram at every position (the TPU-native analogue of SSE's
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 import jax.numpy as jnp
+from jax import lax
 
 # Number of bytes packed into one 32-bit lane.  This mirrors the paper's
 # 4-byte mpsadbw anchor (wsmatch matches the length-4 prefix of the pattern).
 PACK = 4
+# TPU vector lanes: hash_text_blocks contracts rows of _LANES blocks
+_LANES = 128
 
 
 def as_u8(x) -> jnp.ndarray:
@@ -161,18 +166,46 @@ def hash_text_blocks(
     text_u8: jnp.ndarray, weights: jnp.ndarray, kbits: int
 ) -> jnp.ndarray:
     """:func:`hash_blocks` of the aligned beta-byte blocks of (..., n) text,
-    (..., n // beta) int32, without a (..., beta) trailing axis.
+    (..., n // beta) int32, as one MXU contraction over lane-dense rows.
 
-    Built from beta strided slices of the text instead of a reshape to
-    (..., n // beta, beta): on the TPU a minor axis of beta = 8 is padded
-    to a full 128-lane tile, which makes the reshaped operand 16x larger
-    than the text (64 GiB for a 1 GiB corpus).  The int32 sums wrap mod
-    2^32 in any order, so the result equals :func:`hash_blocks` bit for bit.
+    The text is viewed as rows of ROW = 128 * beta bytes (each text's tail
+    zero-padded to whole rows), so a block never straddles a row, and each
+    row is contracted with a block-diagonal (ROW, 4 * 128) matrix holding
+    the four byte planes of the weights: column k * 128 + c sums block c's
+    bytes times the weights' k-th bytes.  The planes recombine as
+    sum_k p_k << 8k in uint32, which wraps mod 2^32 like the int32 dot of
+    :func:`hash_blocks`, so the result equals it bit for bit.  The products
+    are exact: a byte and a weight byte (0-255) are exact in bf16, and one
+    output sums beta = 8 products, at most 520,200 < 2^24, exactly in f32.
+
+    Neither obvious form fits the TPU.  A reshape to (..., n // beta, beta)
+    pads its minor axis of 8 to a 128-lane tile, 16x the text (64 GiB for a
+    1 GiB corpus); beta strided slices of the text compiled for v5e to 8
+    gathers, 11.4 GB accessed per 10 MiB window.
     """
     beta = int(weights.shape[0])
-    nb = text_u8.shape[-1] // beta
-    h = jnp.zeros(text_u8.shape[:-1] + (nb,), jnp.int32)
+    row = _LANES * beta
+    lead, n = text_u8.shape[:-1], text_u8.shape[-1]
+    b, nrows = math.prod(lead), -(-n // row)
+    rows = jnp.pad(
+        text_u8.reshape(b, n), ((0, 0), (0, nrows * row - n))
+    ).reshape(b * nrows, row)
+    # wmat[c * beta + j, k * 128 + c] = byte k of weights[j], 0 off the
+    # diagonal blocks: built from iotas and the weights' scalars, so that it
+    # fuses into the contraction and takes no device buffer of its own
+    r = lax.broadcasted_iota(jnp.int32, (row, 4 * _LANES), 0)
+    col = lax.broadcasted_iota(jnp.int32, (row, 4 * _LANES), 1)
+    w = weights.astype(jnp.uint32)
+    wj = jnp.zeros(r.shape, jnp.uint32)
     for j in range(beta):
-        col = text_u8[..., j : nb * beta : beta].astype(jnp.int32)
-        h = h + col * weights[j]
-    return h & ((1 << kbits) - 1)
+        wj = jnp.where(r % beta == j, w[j], wj)
+    byte = (wj >> (8 * (col // _LANES)).astype(jnp.uint32)) & 0xFF
+    wmat = jnp.where(r // beta == col % _LANES, byte, 0).astype(jnp.bfloat16)
+    p = jnp.dot(
+        rows.astype(jnp.bfloat16), wmat, preferred_element_type=jnp.float32
+    ).astype(jnp.int32).astype(jnp.uint32)
+    h = p[:, :_LANES]
+    for k in range(1, 4):
+        h = h + (p[:, k * _LANES : (k + 1) * _LANES] << (8 * k))
+    h = h.reshape(lead + (nrows * _LANES,))[..., : n // beta]
+    return (h & jnp.uint32((1 << kbits) - 1)).astype(jnp.int32)
